@@ -35,7 +35,8 @@ Fast path (DESIGN.md §3.2–§3.4):
     reference path (numerics-equivalence is CI-guarded).  Under a TP mesh
     the kernel runs ``shard_map``-ped over the "model" axis when the head
     layout supports it (DESIGN.md §11, docs/kernels.md); unsupported
-    layouts fall back loudly, once, with the reason.
+    layouts, and prefill under a mesh, fall back loudly, once, with the
+    reason.
   * **compile/dispatch counters** — ``num_prefill_traces`` /
     ``num_prefill_dispatches`` / ``num_decode_traces`` /
     ``num_decode_dispatches`` mirror ``BGEPredictor``'s recompile-storm
@@ -154,11 +155,16 @@ class InferenceEngine:
     otherwise the engine warns **once**, with the reason, and falls back to
     the XLA decode path (``pallas_fallback`` / ``pallas_fallback_reason``).
     Prefill-side kernels stay single-device, so under a mesh prefill always
-    uses the XLA path (identical numerics; ``T.prefill`` downgrades
-    internally)."""
+    uses the XLA path (identical numerics), with a one-time warning at the
+    first prefill.
+
+    With ``device`` (and no mesh) the engine is a one-chip replica pinned
+    to that device: parameters, slot cache and every dispatch are
+    committed there, so several replicas in one process never share a
+    chip."""
 
     def __init__(self, model_cfg, params, cfg: Optional[EngineConfig] = None,
-                 mesh=None):
+                 mesh=None, device=None):
         if cfg is None:
             cfg = EngineConfig()
         self.pallas_fallback = False
@@ -190,15 +196,20 @@ class InferenceEngine:
         self.model_cfg = model_cfg
         self.cfg = cfg
         self.cache = T.init_cache(model_cfg, cfg.max_slots, cfg.max_len)
-        if mesh is None:
-            self.params = params
-            self._param_sh = self._cache_sh = self._repl = None
-        else:
+        if mesh is not None:
             from repro.launch.partition import engine_shardings
             self._param_sh, self._cache_sh, self._repl = engine_shardings(
                 mesh, model_cfg, params, self.cache)
+        elif device is not None:
+            one = jax.sharding.SingleDeviceSharding(device)
+            self._param_sh = self._cache_sh = self._repl = one
+        else:
+            self._param_sh = self._cache_sh = self._repl = None
+        if self._param_sh is None:
+            self.params = params
+        else:
             # one host copy of params serves any number of pods: each engine
-            # device_puts onto its own (disjoint) mesh
+            # device_puts onto its own (disjoint) devices
             self.params = jax.device_put(params, self._param_sh)
             self.cache = jax.device_put(self.cache, self._cache_sh)
         self.slot_job: List[Optional[int]] = [None] * cfg.max_slots
@@ -215,26 +226,24 @@ class InferenceEngine:
         self._prefill_traces = 0
         self._decode_traces = 0
 
-        mc, ec = model_cfg, cfg
+        mc = model_cfg
+        #: the prefill kernels are single-device: under a mesh prefill
+        #: attends through XLA (warned once, at the first prefill)
+        self._prefill_impl = "xla" if mesh is not None else cfg.attn_impl
 
         def _prefill_fn(params, tokens, cache1, last_index):
             self._prefill_traces += 1  # side effect: once per shape bucket
             batch = {"tokens": tokens}
             return T.prefill(params, mc, batch, cache1,
-                             attn_impl=ec.attn_impl, last_index=last_index,
-                             mesh=mesh)
+                             attn_impl=self._prefill_impl,
+                             last_index=last_index)
 
-        if mesh is None:
-            self._prefill = jax.jit(_prefill_fn)
-        else:
-            # NamedSharding-annotated in/out: params arrive TP-sharded, the
-            # batched sub-cache replicates slots but shards heads/state, and
-            # XLA inserts the all-reduces (wo / w_down partial sums)
-            self._prefill = jax.jit(
-                _prefill_fn,
-                in_shardings=(self._param_sh, self._repl, self._cache_sh,
-                              self._repl),
-                out_shardings=(self._repl, self._cache_sh))
+        # params arrive TP-sharded (or committed to the replica's device),
+        # the batched sub-cache replicates slots but shards heads/state, and
+        # XLA inserts the all-reduces (wo / w_down partial sums)
+        self._prefill = self._jit(
+            _prefill_fn, (self._param_sh, self._repl, self._cache_sh,
+                          self._repl), (self._repl, self._cache_sh))
         self._window_cache: Dict[Tuple[int, int], object] = {}
         #: first generated token (sampled from prefill logits), pending emission
         self._pending_first: Dict[int, int] = {}
@@ -287,15 +296,25 @@ class InferenceEngine:
         warnings.warn(msg, UserWarning, stacklevel=3)
 
     # ------------------------------------------------------------------ #
-    def _canon_cache(self, cache):
-        """Pin a cache pytree to the canonical NamedShardings (mesh mode).
+    def _jit(self, fn, in_shardings, out_shardings):
+        """``jax.jit`` annotated with the engine's placement (a TP mesh or
+        one replica device); plain ``jax.jit`` for an unplaced engine."""
+        if self._param_sh is None:
+            return jax.jit(fn)
+        return jax.jit(fn, in_shardings=in_shardings,
+                       out_shardings=out_shardings)
 
-        The slot gather/scatter runs eagerly between jitted dispatches, and
-        its outputs inherit whatever layout GSPMD propagated; an explicit
-        ``device_put`` keeps the persistent cache (and gathered sub-caches)
-        exactly on the contract the annotated jits expect.  No-op off-mesh
-        and free when the sharding already matches."""
-        if self.mesh is None:
+    def _canon_cache(self, cache):
+        """Pin a cache pytree to the engine's canonical shardings.
+
+        The slot gather/scatter and the host-side ``len`` updates run
+        eagerly between jitted dispatches, and their outputs inherit
+        whatever layout GSPMD propagated (or the default device); an
+        explicit ``device_put`` keeps the persistent cache (and gathered
+        sub-caches) exactly on the contract the annotated jits expect.
+        No-op for an unplaced engine and free when the sharding already
+        matches."""
+        if self._cache_sh is None:
             return cache
         return jax.device_put(cache, self._cache_sh)
 
@@ -320,6 +339,32 @@ class InferenceEngine:
         """Distinct decode batch sizes compaction can dispatch."""
         return len({min(batch_bucket(n), self.cfg.max_slots)
                     for n in range(1, self.cfg.max_slots + 1)})
+
+    def prefill_logits(self, tokens: Sequence[int]) -> np.ndarray:
+        """Last-position logits (V,) of one prompt through this engine's
+        prefill program, taking no slot — a probe for comparing the
+        numerics of two engines (e.g. a TP pod against one chip)."""
+        sl = seq_bucket(len(tokens), self.cfg.max_len,
+                        min_bucket=self.cfg.prefill_bucket)
+        toks = np.full((1, sl), PAD_ID, np.int32)
+        toks[0, :len(tokens)] = tokens
+        logits, _ = self._prefill(
+            self.params, jnp.asarray(toks),
+            T.init_cache(self.model_cfg, 1, self.cfg.max_len),
+            jnp.asarray([len(tokens) - 1], jnp.int32))
+        return np.asarray(logits[0, -1], np.float32)
+
+    def lower_decode_window(self, window: int, batch: int):
+        """Lower, without running, the decode-window program this engine
+        dispatches for ``batch`` slots (``jax.stages.Lowered``) — its text
+        shows which attention path the program holds."""
+        sub = jax.eval_shape(
+            lambda c: _gather_slots(c, jnp.zeros((batch,), jnp.int32)),
+            self.cache)
+        return self._decode_window(window, batch).lower(
+            self.params, sub, jax.ShapeDtypeStruct((batch, 1), jnp.int32),
+            jax.ShapeDtypeStruct((batch,), jnp.bool_),
+            jax.ShapeDtypeStruct(self._key.shape, self._key.dtype))
 
     # ------------------------------------------------------------------ #
     # Chunked prefill
@@ -352,14 +397,9 @@ class InferenceEngine:
                                        attn_impl=ec.attn_impl,
                                        start=start, valid_len=valid)
 
-            if self.mesh is None:
-                self._chunk_cache[padded_len] = jax.jit(fn)
-            else:
-                self._chunk_cache[padded_len] = jax.jit(
-                    fn,
-                    in_shardings=(self._param_sh, self._repl, self._cache_sh,
-                                  self._repl, self._repl),
-                    out_shardings=(self._repl, self._cache_sh))
+            self._chunk_cache[padded_len] = self._jit(
+                fn, (self._param_sh, self._repl, self._cache_sh, self._repl,
+                     self._repl), (self._repl, self._cache_sh))
         return self._chunk_cache[padded_len]
 
     def _alloc_slot(self, job: Job) -> int:
@@ -386,7 +426,7 @@ class InferenceEngine:
         self._chunk_resumed[job.job_id] = bool(job.generated)
         lens = np.asarray(self.cache["len"]).copy()
         lens[slot] = 0
-        self.cache["len"] = jnp.asarray(lens)
+        self.cache = self._canon_cache({**self.cache, "len": jnp.asarray(lens)})
         return slot
 
     def prefill_incomplete(self, job_id: int) -> bool:
@@ -497,9 +537,7 @@ class InferenceEngine:
         if not free:
             raise RuntimeError("no free slot to restore into")
         slot = free[0]
-        sub = jax.device_put(st["cache"])
-        if self.mesh is not None:
-            sub = self._canon_cache(sub)
+        sub = self._canon_cache(jax.device_put(st["cache"]))
         self.cache = self._canon_cache(
             _scatter_slots(self.cache, sub, [slot], 1))
         self.slot_job[slot] = job.job_id
@@ -554,14 +592,9 @@ class InferenceEngine:
                 )
                 return cache, jnp.swapaxes(toks, 0, 1)
 
-            if self.mesh is None:
-                self._window_cache[key2] = jax.jit(fn)
-            else:
-                self._window_cache[key2] = jax.jit(
-                    fn,
-                    in_shardings=(self._param_sh, self._cache_sh, self._repl,
-                                  self._repl, self._repl),
-                    out_shardings=(self._cache_sh, self._repl))
+            self._window_cache[key2] = self._jit(
+                fn, (self._param_sh, self._cache_sh, self._repl, self._repl,
+                     self._repl), (self._cache_sh, self._repl))
         return self._window_cache[key2]
 
     # ------------------------------------------------------------------ #
@@ -644,6 +677,12 @@ class InferenceEngine:
             toks[i, : len(t)] = t
             last_index[i] = len(t) - 1
         cacheN = T.init_cache(self.model_cfg, bb, self.cfg.max_len)
+        if self._prefill_impl != self.cfg.attn_impl:
+            self._warn_once(
+                "pallas_prefill",
+                "attn_impl='pallas' prefill under a mesh runs the XLA "
+                "attention path: the prefill kernels are single-device "
+                "(decode keeps the shard_map'd kernel)")
         self.num_prefill_dispatches += 1
         logits, cacheN = self._prefill(self.params, jnp.asarray(toks), cacheN,
                                        jnp.asarray(last_index))
@@ -824,7 +863,7 @@ class InferenceEngine:
             # scan write — robust to both EOS freezing (which already
             # stopped advancing) and cap truncation (which did not)
             lens[slot] = prev_lens[slot] + max(consumed_scanned, 0)
-        self.cache["len"] = jnp.asarray(lens)
+        self.cache = self._canon_cache({**self.cache, "len": jnp.asarray(lens)})
 
 
 # --------------------------------------------------------------------------- #
@@ -1127,12 +1166,9 @@ def make_tp_pods(model_cfg, params, cfg: Optional[EngineConfig] = None, *,
     frontend's placement policies drive (each pod registers as one node in
     ``GlobalState``; no collective ever crosses pods).
 
-    One host copy of ``params`` is device_put onto every pod's mesh.
-    ``tp=1`` pods are plain single-device engines (no mesh, no collective
-    overhead)."""
-    if tp <= 1:
-        return {n: InferenceEngine(model_cfg, params, cfg)
-                for n in range(n_pods)}
+    One host copy of ``params`` is device_put onto every pod's devices.
+    ``tp=1`` pods are single-device engines (no mesh, no collective
+    overhead), pod ``n`` committed to device ``n``."""
     from repro.launch.mesh import make_mesh
     devices = list(jax.devices() if devices is None else devices)
     need = n_pods * tp
@@ -1141,6 +1177,9 @@ def make_tp_pods(model_cfg, params, cfg: Optional[EngineConfig] = None, *,
             f"{n_pods} pods x TP={tp} need {need} devices, have "
             f"{len(devices)} — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N")
+    if tp <= 1:
+        return {n: InferenceEngine(model_cfg, params, cfg, device=devices[n])
+                for n in range(n_pods)}
     return {
         n: InferenceEngine(
             model_cfg, params, cfg,

@@ -8,6 +8,9 @@ Blocked online-softmax attention with explicit VMEM tiling:
 Supports GQA (kv-head index derived statically from the query head), causal
 masking with a query offset, and sliding-window (SWA) masking.  Block sizes
 default to 128×128 — MXU-aligned on the (sublane, lane) = (8, 128) layout.
+Q/K/V are viewed (free reshape) as ``(B, S, heads*D)`` and one head's
+``(block, D)`` tile is selected by a block index on the fused last axis, so
+the blocks satisfy Mosaic's tiling rule whenever ``D`` is a multiple of 128.
 
 Validated on CPU in ``interpret=True`` mode against ``ref.reference_attention``.
 """
@@ -45,11 +48,11 @@ def _attn_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (BQ, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (BK, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # (BK, D)
+    q = q_ref[...].astype(jnp.float32)  # (BQ, D)
+    k = k_ref[...].astype(jnp.float32)  # (BK, D)
+    v = v_ref[...].astype(jnp.float32)  # (BK, D)
 
-    s = jnp.dot(q, k.T) * scale  # (BQ, BK)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (BQ, BK)
 
     qi = pl.program_id(2)
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_offset
@@ -61,19 +64,19 @@ def _attn_kernel(
         mask &= k_pos > (q_pos - window)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_ref[...]  # (BQ, 1)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
+    p = jnp.exp(s - m_cur)
     p = jnp.where(mask, p, 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(p, v)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v)
     m_ref[...] = m_cur
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -110,21 +113,21 @@ def flash_attention(
         q_offset=q_offset,
     )
     grid = (b, h, n_q, n_k)
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, block_q, d), lambda b_, h_, qi, ki: (b_, qi, h_))
+    kv_spec = pl.BlockSpec((None, block_k, d),
+                           lambda b_, h_, qi, ki: (b_, ki, h_ // rep))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h_, qi, ki: (b_, ki, h_ // rep, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h_, qi, ki: (b_, ki, h_ // rep, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, sq, h * d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q.reshape(b, sq, h * d), k.reshape(b, skv, kh * d),
+      v.reshape(b, skv, kh * d))
+    return out.reshape(b, sq, h, d)

@@ -11,6 +11,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e per-chip constants (roofline denominators)
 PEAK_FLOPS_BF16 = 197e12      # FLOP/s
@@ -37,12 +38,11 @@ def make_mesh(shape, axes=("data", "model"), *, devices=None):
             "XLA_FLAGS=--xla_force_host_platform_device_count=N before jax "
             "initialises (see repro.launch.dryrun)"
         )
-    try:
-        return jax.make_mesh(shape, tuple(axes), devices=devices[:n])
-    except TypeError:  # older make_mesh without devices kwarg
-        from jax.sharding import Mesh
-
-        return Mesh(np.asarray(devices[:n]).reshape(shape), tuple(axes))
+    # Auto axes: the engine's jits are annotated with NamedShardings and
+    # leave the rest to GSPMD propagation (Explicit axes would demand
+    # out_sharding on every gather)
+    return jax.make_mesh(shape, tuple(axes), devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

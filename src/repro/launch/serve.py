@@ -1,13 +1,17 @@
 """Serving launcher — the Kubernetes-pod entrypoint analogue.
 
-Assembles the full ELIS stack from CLI args: N backend workers (each an
-InferenceEngine on the selected ``--arch``, reduced configs on CPU), the
+Assembles the full ELIS stack from CLI args: N backend pods (each an
+InferenceEngine on the selected ``--arch`` with seeded random weights, on
+its own device(s); reduced widths unless ``--published-widths``), the
 frontend scheduler with the chosen policy, and either a trace file from
-``repro.launch.generate`` or a synthetic stream.
+``repro.launch.generate`` or a synthetic stream.  Exits non-zero when any
+request does not finish.
 
     python -m repro.launch.serve --arch qwen2-1.5b --policy isrtf \
-        --workers 2 --trace trace.jsonl
+        --trace trace.jsonl
     python -m repro.launch.serve --arch mamba2-130m --policy isrtf --n 12
+    python -m repro.launch.serve --arch qwen2-1.5b --published-widths \
+        --slots 16 --max-len 2048 --attn-impl pallas     # on a TPU
 """
 from __future__ import annotations
 
@@ -41,12 +45,8 @@ from repro.data.workload import (
     build_scale_workload,
     scale_workload_requests,
 )
-from repro.engine import (
-    EngineConfig,
-    EngineExecutor,
-    InferenceEngine,
-    make_tp_pods,
-)
+from repro.engine import EngineConfig, EngineExecutor, make_tp_pods
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.models.encoder import EncoderArchConfig
 from repro.training import latest_step, restore_checkpoint
@@ -165,16 +165,24 @@ def build_predictor(args):
     return wrap_calibration(base, cal)
 
 
-def main() -> None:
+def main(argv=None) -> int:
+    """Serve one request stream; returns 0 when every request finished."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b", choices=list(list_archs()))
+    ap.add_argument("--published-widths", action="store_true",
+                    help="serve the architecture at its published widths and "
+                         "dtype (default: the reduced CPU smoke config)")
+    ap.add_argument("--attn-impl", default="xla", choices=["xla", "pallas"],
+                    help="attention implementation: XLA einsums, or the "
+                         "Pallas flash-attention/flash-decode kernels")
     ap.add_argument("--policy", default="isrtf",
                     choices=["fcfs", "sjf", "isrtf", "mlfq"])
     ap.add_argument("--predictor", default="oracle",
                     choices=["oracle", "bge"])
     ap.add_argument("--predictor-ckpt", default=None,
                     help="restore a trained BGE predictor (train_predictor.py)")
-    ap.add_argument("--workers", type=int, default=1)
+    ap.add_argument("--workers", type=int, default=1,
+                    help="one-device pods, one per device (same as --mesh Nx1)")
     ap.add_argument("--mesh", default=None, metavar="DxM",
                     help="shard the serving fleet over a DxM data×model "
                          "device mesh: D tensor-parallel pods of M devices "
@@ -200,6 +208,8 @@ def main() -> None:
     ap.add_argument("--rebalance-threshold", type=float, default=200.0,
                     help="predicted-token imbalance that triggers stealing")
     ap.add_argument("--slots", type=int, default=2)
+    ap.add_argument("--max-len", type=int, default=512,
+                    help="per-slot KV capacity in tokens (prompt + output)")
     ap.add_argument("--window", type=int, default=8)
     ap.add_argument("--repredict-every", type=int, default=1,
                     help="full predictor re-score every N windows (between "
@@ -250,33 +260,40 @@ def main() -> None:
     ap.add_argument("--rate", type=float, default=1.5)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-preemption", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch).reduced()
+    enable_compile_cache()
+    devices = jax.devices()
+    print(f"[serve] platform={devices[0].platform} "
+          f"device_kind={devices[0].device_kind} devices={len(devices)}",
+          file=sys.stderr)
+    cfg = get_config(args.arch)
+    if not args.published_widths:
+        cfg = cfg.reduced()
     ecfg = EngineConfig(
-        max_slots=args.slots, max_len=512, max_output=args.max_output,
-        eos_id=-1, respect_job_max=True)
-    params = init_params(jax.random.PRNGKey(0), cfg)
+        max_slots=args.slots, max_len=args.max_len,
+        max_output=args.max_output, eos_id=-1, respect_job_max=True,
+        attn_impl=args.attn_impl)
     if args.prefill_chunk is not None and args.prefill_chunk < 1:
         sys.exit(f"--prefill-chunk must be >= 1, got {args.prefill_chunk}")
-    if args.mesh:
-        try:
-            d, m = parse_mesh(args.mesh)
-        except ValueError as e:
-            sys.exit(str(e))
-        n_pods = args.pods if args.pods is not None else d
-        if not 1 <= n_pods <= d:
-            sys.exit(f"--pods {n_pods} outside the mesh's {d} data rows")
-        args.workers = n_pods
+    try:
+        d, m = parse_mesh(args.mesh) if args.mesh else (args.workers, 1)
+    except ValueError as e:
+        sys.exit(str(e))
+    n_pods = args.pods if args.pods is not None else d
+    if not 1 <= n_pods <= d:
+        sys.exit(f"--pods {n_pods} outside the mesh's {d} data rows")
+    args.workers = n_pods
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    try:
         engines = make_tp_pods(cfg, params, ecfg, n_pods=n_pods, tp=m)
-        print(f"[serve] {n_pods} TP={m} pod(s) x {args.slots} slots over "
-              f"{n_pods * m}/{len(jax.devices())} devices, {cfg.arch_id}, "
-              f"policy={args.policy}", file=sys.stderr)
-    else:
-        engines = {n: InferenceEngine(cfg, params, ecfg)
-                   for n in range(args.workers)}
-        print(f"[serve] {args.workers} worker(s) x {args.slots} slots, "
-              f"{cfg.arch_id}, policy={args.policy}", file=sys.stderr)
+    except RuntimeError as e:
+        sys.exit(str(e))
+    del params  # every pod holds its own device copy
+    print(f"[serve] {n_pods} TP={m} pod(s) x {args.slots} slots over "
+          f"{n_pods * m}/{len(devices)} devices, {cfg.arch_id} "
+          f"({cfg.dtype}), attn={args.attn_impl}, policy={args.policy}",
+          file=sys.stderr)
     # prediction-aware placement / rebalancing consume length predictions
     # even when the ordering policy (fcfs/mlfq) does not; rebalancing is
     # meaningful only across workers
@@ -336,6 +353,7 @@ def main() -> None:
             "queuing_delay_s": round(r.queuing_delay, 3),
             "preemptions": r.n_preemptions,
             "migrations": r.n_migrations,
+            "tokens": list(r.tokens),
         }
         if args.scenario:
             rec["tenant"] = r.tenant
@@ -375,7 +393,12 @@ def main() -> None:
             {t: tm["jct_mean"] for t, tm in tenants.items()})
         print(f"[serve]   fairness(max/min mean JCT) {fair:.2f}",
               file=sys.stderr)
+    if len(finished) < len(responses):
+        print(f"[serve] FAILED: {len(responses) - len(finished)} request(s) "
+              "did not finish", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

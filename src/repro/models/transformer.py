@@ -402,21 +402,16 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None,
 
 def prefill(params: Params, cfg, batch: Dict, cache: Cache,
             *, attn_impl: str = "xla", moe_impl: str = "dense",
-            last_index: Optional[jnp.ndarray] = None, mesh=None):
+            last_index: Optional[jnp.ndarray] = None):
     """Process the full prompt, fill the cache, return last-position logits.
 
     ``last_index`` (B,) selects the position whose logits are returned —
     engines right-pad prompts to buckets and need the *true* last position.
 
-    ``mesh`` marks a sharded (TP) caller.  The prefill-side Pallas kernels
-    (flash_attention, ssd_scan) are single-device, so under a mesh
-    ``attn_impl="pallas"`` downgrades to ``"xla"`` here — numerics are
-    identical either way (the xla==pallas identity contract, CI-asserted)
-    and prefill is off the steady-state decode hot loop.  Mesh-aware decode
-    stays on the real kernel via :func:`decode_step` (DESIGN.md §11).
+    The prefill-side Pallas kernels (flash_attention, ssd_scan) are
+    single-device: a tensor-parallel caller passes ``attn_impl="xla"``
+    (the engine does, and says so once — DESIGN.md §11).
     """
-    if mesh is not None and attn_impl == "pallas":
-        attn_impl = "xla"
     h, pos = embed_inputs(params, cfg, batch)
     s = h.shape[1]
     cos_sin = L.positional_cos_sin(cfg, pos) if cfg.rope_type in ("rope", "mrope") else None

@@ -1,16 +1,8 @@
 import os
-import sys
 
 # Tests run on the single real CPU device (the 512-device override is
 # dry-run-only, set inside repro.launch.dryrun before jax init).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# Property tests use hypothesis; fall back to the bundled minimal shim when
-# the real package is absent (containers without network access).
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_shims"))
 
 import jax
 

@@ -503,6 +503,30 @@ def test_make_tp_pods_disjoint_and_identical():
         make_tp_pods(cfg, params, ecfg, n_pods=too_many, tp=2)
 
 
+@needs2
+def test_make_tp_pods_tp1_one_device_per_pod():
+    """One-device replicas: pod n commits its params and slot cache to
+    device n, stays there through serving, and decodes the same tokens as
+    an unplaced engine."""
+    cfg = get_config("qwen2-1.5b").reduced()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    ecfg = EngineConfig(max_slots=2, max_len=64, max_output=16, eos_id=-1)
+    devices = jax.devices()
+    pods = make_tp_pods(cfg, params, ecfg, n_pods=2, tp=1)
+    ref, _ = InferenceEngine(cfg, params, ecfg).run_window(
+        [_mk(0, [11, 22, 33])], 6)
+    for n, eng in pods.items():
+        assert eng.mesh is None
+        logits = eng.prefill_logits([11, 22, 33])
+        assert int(np.argmax(logits)) == ref[0][0]  # the first emission
+        toks, _ = eng.run_window([_mk(0, [11, 22, 33])], 6)
+        assert toks == ref
+        leaves = jax.tree_util.tree_leaves((eng.params, eng.cache))
+        assert {d for leaf in leaves for d in leaf.devices()} == {devices[n]}
+    with pytest.raises(RuntimeError, match="devices"):
+        make_tp_pods(cfg, params, ecfg, n_pods=len(devices) + 1, tp=1)
+
+
 # --------------------------------------------------------------------------- #
 # Per-node executor surface (runs on one device)
 # --------------------------------------------------------------------------- #
