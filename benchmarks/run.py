@@ -33,7 +33,6 @@ def main() -> None:
         predictor_calibration,
         prefill_preempt,
         rank_sched,
-        roofline,
         scheduler_overhead,
         sim_scale,
         table2_predictor,
@@ -114,10 +113,6 @@ def main() -> None:
               and r.get("policy") == "mlfq"), "?")) + ";sigma_sweep=" + "/".join(
              f"{r['gain_vs_fcfs_pct']:.0f}" for r in rows
              if r.get("ablation") == "predictor_quality" and "sigma_rel" in r)),
-        ("roofline", roofline.run,
-         lambda rows: f"pairs={len(rows)};"
-                      f"collective_bound={sum(1 for r in rows if r['dominant']=='collective')};"
-                      f"memory_bound={sum(1 for r in rows if r['dominant']=='memory')}"),
     ]
 
     print("name,us_per_call,derived")

@@ -72,6 +72,7 @@ from repro.core.scheduler import (
     select_fills,
     select_preemptions,
 )
+from repro.spans import span
 
 __all__ = [
     "Backend", "ELISFrontend", "Event", "ExecResult",
@@ -303,7 +304,8 @@ class ELISFrontend:
         t, _, _, kind, data = heapq.heappop(self._events)
         self.now = max(self.now, t)
         if kind == "arrival":
-            self._on_arrival(data, t, out)
+            with span("elis.sched.arrival"):
+                self._on_arrival(data, t, out)
         elif kind == "node_free":
             self._on_node_free(data, t, out)
         elif kind == "deadline":
@@ -470,7 +472,8 @@ class ELISFrontend:
         self._sweep_cancelled(node, now, out)
         if self._rebalance_active:
             self._rebalance(node, now, out)
-        batch = self._form_batch(node, now, out)
+        with span("elis.sched.form_batch"):
+            batch = self._form_batch(node, now, out)
         if not batch:
             self.node_busy[node] = False
             return
@@ -484,6 +487,13 @@ class ELISFrontend:
         else:
             res = self.executor.execute(node, batch,
                                         self.cfg.scheduler.window, now)
+        with span("elis.sched.apply"):
+            self._apply_window(node, batch, res, now, out)
+
+    def _apply_window(self, node: int, batch: List[Job], res: ExecResult,
+                      now: float, out: List[Event]) -> None:
+        """Emit a window's tokens, finish and evict the jobs it completed,
+        and schedule the node's next window."""
         end = now + res.duration
         # the horizon this window runs to — least_eta placement reads it
         self.state.note_busy(node, end)
@@ -559,8 +569,9 @@ class ELISFrontend:
         widx = self._windows[node]
         self._windows[node] = widx + 1
         stride = max(self.cfg.scheduler.repredict_every, 1)
-        run_eff, wait_eff = score_pool(self.policy, running, waiting, now,
-                                       full=(widx % stride == 0))
+        with span("elis.sched.score"):
+            run_eff, wait_eff = score_pool(self.policy, running, waiting,
+                                           now, full=(widx % stride == 0))
         # step 2 reuses these (no second scoring pass)
         eff = {j.job_id: e for j, e in zip(waiting, wait_eff)}
 
@@ -586,58 +597,61 @@ class ELISFrontend:
         backend_free = fc(node) if fc is not None else None
 
         # 1. preemption: displace low-priority running jobs (margin-gated)
-        swaps = select_preemptions(
-            list(zip(run_eff, running)), list(zip(wait_eff, waiting)),
-            self.cfg.preemption,
-        )
-        pcfg = self.cfg.preemption
-        for victim, repl in swaps:
-            running.remove(victim)
-            victim.state = JobState.PREEMPTED
-            victim.n_preemptions += 1
-            victim.record_enqueue(now)
-            waiting.append(victim)
-            # swap-vs-recompute (PreemptionConfig.policy): costs are priced
-            # BEFORE the offload/evict mutates the victim's cache state
-            mode = "recompute"
-            if pcfg.policy != "recompute":
-                mode = decide_preempt(
-                    pcfg, self.executor.preempt_costs(node, victim),
-                    cached_expected_remaining(victim))
-            if mode == "swap" and not self.executor.offload(node, victim):
-                mode = "recompute"  # backend can't swap this job
-            if mode == "recompute":
-                self.executor.evict(node, victim)
-            out.append(Event(now, "preempted", victim.job_id))
-            # freshly re-enqueued at ``now`` ⇒ zero aging: re-band the same
-            # (possibly stale-decayed) raw priority this window's scoring
-            # pass used — NOT the undecayed cached prediction, which would
-            # rank the victim inconsistently against stale-scored waiters.
-            # The prefill debt is re-read AFTER the evict/offload above: a
-            # recompute-evicted victim's debt is its whole context, a
-            # swapped one's is unchanged
-            eff[victim.job_id] = effective_priority(
-                self.cfg.scheduler, victim,
-                cached_raw_priority(victim)
-                + prefill_debt(self.cfg.scheduler, victim), now)
-            eff.pop(repl.job_id, None)
-            waiting.remove(repl)
-            repl.state = JobState.RUNNING
-            repl.record_dispatch(now)
-            running.append(repl)
+        with span("elis.sched.preempt"):
+            swaps = select_preemptions(
+                list(zip(run_eff, running)), list(zip(wait_eff, waiting)),
+                self.cfg.preemption,
+            )
+            pcfg = self.cfg.preemption
+            for victim, repl in swaps:
+                running.remove(victim)
+                victim.state = JobState.PREEMPTED
+                victim.n_preemptions += 1
+                victim.record_enqueue(now)
+                waiting.append(victim)
+                # swap-vs-recompute (PreemptionConfig.policy): costs are priced
+                # BEFORE the offload/evict mutates the victim's cache state
+                mode = "recompute"
+                if pcfg.policy != "recompute":
+                    mode = decide_preempt(
+                        pcfg, self.executor.preempt_costs(node, victim),
+                        cached_expected_remaining(victim))
+                if mode == "swap" and not self.executor.offload(node, victim):
+                    mode = "recompute"  # backend can't swap this job
+                if mode == "recompute":
+                    self.executor.evict(node, victim)
+                out.append(Event(now, "preempted", victim.job_id))
+                # freshly re-enqueued at ``now`` ⇒ zero aging: re-band the
+                # same (possibly stale-decayed) raw priority this window's
+                # scoring pass used — NOT the undecayed cached prediction,
+                # which would rank the victim inconsistently against
+                # stale-scored waiters.  The prefill debt is re-read AFTER
+                # the evict/offload above: a recompute-evicted victim's debt
+                # is its whole context, a swapped one's is unchanged
+                eff[victim.job_id] = effective_priority(
+                    self.cfg.scheduler, victim,
+                    cached_raw_priority(victim)
+                    + prefill_debt(self.cfg.scheduler, victim), now)
+                eff.pop(repl.job_id, None)
+                waiting.remove(repl)
+                repl.state = JobState.RUNNING
+                repl.record_dispatch(now)
+                running.append(repl)
 
         # 2. fill free slots with the best remaining waiters, reusing the
         #    step-1 priorities (membership changes were patched in above);
         #    the backend's own capacity bounds admissions when it is tighter
         #    than the configured batch size
-        free = cap - len(running)
-        if backend_free is not None:
-            free = min(free, backend_free)
-        if free > 0 and waiting:
-            picks = select_fills([eff[job.job_id] for job in waiting], free)
-            for job in [waiting[k] for k in picks]:
-                waiting.remove(job)
-                job.state = JobState.RUNNING
-                job.record_dispatch(now)
-                running.append(job)
+        with span("elis.sched.fill"):
+            free = cap - len(running)
+            if backend_free is not None:
+                free = min(free, backend_free)
+            if free > 0 and waiting:
+                picks = select_fills([eff[job.job_id] for job in waiting],
+                                     free)
+                for job in [waiting[k] for k in picks]:
+                    waiting.remove(job)
+                    job.state = JobState.RUNNING
+                    job.record_dispatch(now)
+                    running.append(job)
         return list(running)

@@ -94,6 +94,7 @@ from repro.data.tokenizer import CLS_ID, PAD_ID, SEP_ID
 from repro.models import encoder as E
 from repro.models.layers import dense_init
 from repro.models.objective import RankingConfig, ranking_loss
+from repro.spans import span
 from repro.training import AdamWConfig, train
 
 
@@ -467,15 +468,11 @@ class BGEPredictor(LengthPredictor):
         rank_raw = apply_head(params["rank_head"], feats)
         return out, jnp.exp(jnp.clip(rank_raw, -2.0, 8.0))
 
-    def _run_tokens(self, token_lists: Sequence[Sequence[int]]):
-        """Pad to the (batch, seq) bucket and run ONE jitted dispatch.
-
-        Returns ``(raw_output, b)`` where ``raw_output`` is the jit result
-        — a ``(means, rank_scores)`` tuple when the ranking head is enabled
-        — and ``b`` the true batch size for slicing padding off."""
+    def _pad(self, token_lists: Sequence[Sequence[int]]
+             ) -> Tuple[np.ndarray, np.ndarray]:
+        """``(tokens, mask)`` padded to the (batch, seq) bucket."""
         ml = self.cfg.max_len
         b = len(token_lists)
-        self.num_dispatches += 1
         longest = max(min(len(t), ml) for t in token_lists)
         bb = batch_bucket(b)
         sl = seq_bucket(longest, ml)
@@ -485,7 +482,18 @@ class BGEPredictor(LengthPredictor):
             t = list(t)[:sl]
             toks[i, : len(t)] = t
             mask[i, : len(t)] = True
-        return self._apply(self.params, toks, mask), b
+        return toks, mask
+
+    def _run(self, toks: np.ndarray, mask: np.ndarray, b: int
+             ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """ONE jitted dispatch of a padded batch, read back to the host:
+        ``(means, rank_scores)`` of its first ``b`` rows (rank scores None
+        without the ranking head)."""
+        self.num_dispatches += 1
+        out = self._apply(self.params, toks, mask)
+        if self.cfg.ranking is None:
+            return np.asarray(out)[:b], None
+        return np.asarray(out[0])[:b], np.asarray(out[1])[:b]
 
     def predict_tokens(self, token_lists: Sequence[Sequence[int]]) -> np.ndarray:
         """One batched inference dispatch, shape-bucketed.
@@ -498,10 +506,7 @@ class BGEPredictor(LengthPredictor):
         sliced off before returning."""
         if len(token_lists) == 0:
             return np.zeros((0,))
-        out, b = self._run_tokens(token_lists)
-        if self.cfg.ranking is not None:
-            out = out[0]
-        return np.asarray(out)[:b]
+        return self._run(*self._pad(token_lists), len(token_lists))[0]
 
     def predict_tokens_ranked(
             self, token_lists: Sequence[Sequence[int]]
@@ -513,8 +518,7 @@ class BGEPredictor(LengthPredictor):
                 "PredictorConfig(ranking=RankingConfig(...))")
         if len(token_lists) == 0:
             return np.zeros((0,)), np.zeros((0,))
-        (m, r), b = self._run_tokens(token_lists)
-        return np.asarray(m)[:b], np.asarray(r)[:b]
+        return self._run(*self._pad(token_lists), len(token_lists))
 
     # -------------------------------------------------------------- #
     def _job_input(self, job: Job) -> List[int]:
@@ -533,14 +537,21 @@ class BGEPredictor(LengthPredictor):
         jobs = list(jobs)
         if not jobs:
             return []
-        if self.cfg.ranking is None:
-            return super().predict(jobs)
-        # two-head path: one fused dispatch fills both heads, and the
-        # ranking score rides on the prediction next to the calibrated mean
-        means, ranks = self.predict_tokens_ranked(
-            [self._job_input(j) for j in jobs])
-        return [replace(self._prediction(j, float(m)), rank_score=float(r))
-                for j, m, r in zip(jobs, means, ranks)]
+        with span("elis.predict", jobs=len(jobs)):
+            with span("elis.predict.pad"):
+                toks, mask = self._pad([self._job_input(j) for j in jobs])
+            with span("elis.predict.sync"):
+                means, ranks = self._run(toks, mask, len(jobs))
+            with span("elis.predict.post"):
+                preds = [self._prediction(j, float(m))
+                         for j, m in zip(jobs, means)]
+                if ranks is None:
+                    return preds
+                # two-head path: one fused dispatch fills both heads, and
+                # the ranking score rides on the prediction next to the
+                # calibrated mean
+                return [replace(p, rank_score=float(r))
+                        for p, r in zip(preds, ranks)]
 
     def _prediction(self, job: Job, mean: float) -> LengthPrediction:
         if self.resid_sigma <= 0.0:

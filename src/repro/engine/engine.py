@@ -40,7 +40,11 @@ Fast path (DESIGN.md §3.2–§3.4):
   * **compile/dispatch counters** — ``num_prefill_traces`` /
     ``num_prefill_dispatches`` / ``num_decode_traces`` /
     ``num_decode_dispatches`` mirror ``BGEPredictor``'s recompile-storm
-    hooks; ``EngineExecutor.counters()`` aggregates them and
+    hooks, and ``num_host_syncs`` counts device-to-host reads (each one an
+    ``elis.engine.sync`` span, :mod:`repro.spans`); the jitted programs are
+    named ``prefill``, ``prefill_chunk`` and ``decode_window``, so a device
+    trace names them ``jit_prefill``, ``jit_prefill_chunk`` and
+    ``jit_decode_window``; ``EngineExecutor.counters()`` aggregates them and
     ``EngineExecutor.calibrated_profile()`` fits the measured window
     durations back onto the simulator's latency model (live↔sim
     calibration).
@@ -63,6 +67,7 @@ from repro.data.dataset import batch_bucket, n_shape_buckets, seq_bucket
 from repro.data.tokenizer import EOS_ID, PAD_ID
 from repro.engine.sampler import SamplerConfig, sample
 from repro.models import transformer as T
+from repro.spans import span
 
 #: recurrent-state families prefill at exact length (pad positions would be
 #: absorbed into the state), so they keep serial batch-1 admission
@@ -223,6 +228,8 @@ class InferenceEngine:
         #: buckets, not calls
         self.num_prefill_dispatches = 0
         self.num_decode_dispatches = 0
+        #: device-to-host reads (``_sync``)
+        self.num_host_syncs = 0
         self._prefill_traces = 0
         self._decode_traces = 0
 
@@ -231,7 +238,7 @@ class InferenceEngine:
         #: attends through XLA (warned once, at the first prefill)
         self._prefill_impl = "xla" if mesh is not None else cfg.attn_impl
 
-        def _prefill_fn(params, tokens, cache1, last_index):
+        def prefill(params, tokens, cache1, last_index):
             self._prefill_traces += 1  # side effect: once per shape bucket
             batch = {"tokens": tokens}
             return T.prefill(params, mc, batch, cache1,
@@ -242,7 +249,7 @@ class InferenceEngine:
         # the batched sub-cache replicates slots but shards heads/state, and
         # XLA inserts the all-reduces (wo / w_down partial sums)
         self._prefill = self._jit(
-            _prefill_fn, (self._param_sh, self._repl, self._cache_sh,
+            prefill, (self._param_sh, self._repl, self._cache_sh,
                           self._repl), (self._repl, self._cache_sh))
         self._window_cache: Dict[Tuple[int, int], object] = {}
         #: first generated token (sampled from prefill logits), pending emission
@@ -296,6 +303,14 @@ class InferenceEngine:
         warnings.warn(msg, UserWarning, stacklevel=3)
 
     # ------------------------------------------------------------------ #
+    def _sync(self, x):
+        """Read ``x`` (an array or pytree) back to the host: the one door
+        for every device-to-host read of the engine, counted in
+        ``num_host_syncs``."""
+        with span("elis.engine.sync"):
+            self.num_host_syncs += 1
+            return jax.device_get(x)
+
     def _jit(self, fn, in_shardings, out_shardings):
         """``jax.jit`` annotated with the engine's placement (a TP mesh or
         one replica device); plain ``jax.jit`` for an unplaced engine."""
@@ -352,7 +367,7 @@ class InferenceEngine:
             self.params, jnp.asarray(toks),
             T.init_cache(self.model_cfg, 1, self.cfg.max_len),
             jnp.asarray([len(tokens) - 1], jnp.int32))
-        return np.asarray(logits[0, -1], np.float32)
+        return self._sync(logits[0, -1]).astype(np.float32)
 
     def lower_decode_window(self, window: int, batch: int):
         """Lower, without running, the decode-window program this engine
@@ -391,43 +406,45 @@ class InferenceEngine:
         if padded_len not in self._chunk_cache:
             mc, ec = self.model_cfg, self.cfg
 
-            def fn(params, tokens, cache1, start, valid):
+            def prefill_chunk(params, tokens, cache1, start, valid):
                 self._chunk_traces += 1  # side effect: once per shape
                 return T.prefill_chunk(params, mc, {"tokens": tokens}, cache1,
                                        attn_impl=ec.attn_impl,
                                        start=start, valid_len=valid)
 
             self._chunk_cache[padded_len] = self._jit(
-                fn, (self._param_sh, self._repl, self._cache_sh, self._repl,
-                     self._repl), (self._repl, self._cache_sh))
+                prefill_chunk, (self._param_sh, self._repl, self._cache_sh,
+                                self._repl, self._repl),
+                (self._repl, self._cache_sh))
         return self._chunk_cache[padded_len]
 
-    def _alloc_slot(self, job: Job) -> int:
-        """Claim a slot WITHOUT prefilling (chunked admission): the slot's
-        ``len`` is zeroed and the prompt is span-written chunk by chunk
-        across subsequent windows (stale K/V from a previous occupant is
-        dead weight behind the kv_len mask, exactly as after a one-shot
-        scatter)."""
+    def _alloc_slots(self, jobs: Sequence[Job]) -> None:
+        """Claim a slot for each job WITHOUT prefilling (chunked
+        admission): the slots' ``len`` is zeroed and each prompt is
+        span-written chunk by chunk across subsequent windows (stale K/V
+        from a previous occupant is dead weight behind the kv_len mask,
+        exactly as after a one-shot scatter)."""
         free = [s for s, owner in enumerate(self.slot_job) if owner is None]
-        if not free:
+        if len(jobs) > len(free):
             raise RuntimeError("no free slot to allocate")
-        slot = free[0]
-        toks = self._resume_tokens(job)
-        if len(toks) > self.cfg.max_len:
-            raise ValueError(
-                f"prompt of {len(toks)} tokens exceeds max_len="
-                f"{self.cfg.max_len}")
-        self.slot_job[slot] = job.job_id
-        self.slot_of[job.job_id] = slot
-        self.last_token[slot, 0] = PAD_ID
-        self._prefill_cursor[job.job_id] = 0
-        self._chunk_target[job.job_id] = len(toks)
-        self._chunk_tokens[job.job_id] = toks
-        self._chunk_resumed[job.job_id] = bool(job.generated)
-        lens = np.asarray(self.cache["len"]).copy()
-        lens[slot] = 0
-        self.cache = self._canon_cache({**self.cache, "len": jnp.asarray(lens)})
-        return slot
+        streams = [self._resume_tokens(job) for job in jobs]
+        for toks in streams:
+            if len(toks) > self.cfg.max_len:
+                raise ValueError(
+                    f"prompt of {len(toks)} tokens exceeds max_len="
+                    f"{self.cfg.max_len}")
+        lens = self._sync(self.cache["len"]).copy()
+        for job, slot, toks in zip(jobs, free, streams):
+            self.slot_job[slot] = job.job_id
+            self.slot_of[job.job_id] = slot
+            self.last_token[slot, 0] = PAD_ID
+            self._prefill_cursor[job.job_id] = 0
+            self._chunk_target[job.job_id] = len(toks)
+            self._chunk_tokens[job.job_id] = toks
+            self._chunk_resumed[job.job_id] = bool(job.generated)
+            lens[slot] = 0
+        self.cache = self._canon_cache(
+            {**self.cache, "len": jnp.asarray(lens)})
 
     def prefill_incomplete(self, job_id: int) -> bool:
         """True while a chunk-admitted job still has prompt tokens to
@@ -465,7 +482,7 @@ class InferenceEngine:
                 self.last_token[slot, 0] = job.generated[-1]
                 self.resume_context_tokens += 1  # the seed token's KV write
             else:
-                first = int(np.argmax(np.asarray(logits)[0, -1]))
+                first = int(np.argmax(self._sync(logits)[0, -1]))
                 self._pending_first[jid] = first
                 self.last_token[slot, 0] = first
 
@@ -488,18 +505,19 @@ class InferenceEngine:
         slot = self.slot_of.get(job_id)
         if slot is None:
             return False
-        ctx = int(np.asarray(self.cache["len"])[slot])
-        sub = _gather_slots(self.cache, jnp.asarray([slot], jnp.int32))
-        self._host_stash[job_id] = {
-            "cache": jax.device_get(sub),
-            "last": int(self.last_token[slot, 0]),
-            "pending": self._pending_first.get(job_id),
-            "cursor": self._prefill_cursor.get(job_id),
-            "target": self._chunk_target.get(job_id),
-            "tokens": self._chunk_tokens.get(job_id),
-            "resumed": self._chunk_resumed.get(job_id),
-            "ctx": ctx,
-        }
+        with span("elis.engine.offload"):
+            ctx = int(self._sync(self.cache["len"])[slot])
+            sub = _gather_slots(self.cache, jnp.asarray([slot], jnp.int32))
+            self._host_stash[job_id] = {
+                "cache": self._sync(sub),
+                "last": int(self.last_token[slot, 0]),
+                "pending": self._pending_first.get(job_id),
+                "cursor": self._prefill_cursor.get(job_id),
+                "target": self._chunk_target.get(job_id),
+                "tokens": self._chunk_tokens.get(job_id),
+                "resumed": self._chunk_resumed.get(job_id),
+                "ctx": ctx,
+            }
         self.stash_tokens += ctx
         if self.swap_pool_tokens is not None:
             # evict coldest-first until under the watermark; the fresh
@@ -569,7 +587,7 @@ class InferenceEngine:
         if key2 not in self._window_cache:
             mc, ec = self.model_cfg, self.cfg
 
-            def fn(params, cache, last_tokens, alive, rng):
+            def decode_window(params, cache, last_tokens, alive, rng):
                 self._decode_traces += 1  # side effect: once per shape
 
                 def step(carry, _):
@@ -593,8 +611,9 @@ class InferenceEngine:
                 return cache, jnp.swapaxes(toks, 0, 1)
 
             self._window_cache[key2] = self._jit(
-                fn, (self._param_sh, self._cache_sh, self._repl, self._repl,
-                     self._repl), (self._cache_sh, self._repl))
+                decode_window, (self._param_sh, self._cache_sh, self._repl,
+                                self._repl, self._repl),
+                (self._cache_sh, self._repl))
         return self._window_cache[key2]
 
     # ------------------------------------------------------------------ #
@@ -640,11 +659,12 @@ class InferenceEngine:
                     f"slots, engine has {self.free_slots()}")
             serial = (not self.cfg.batched_prefill
                       or self.model_cfg.family in EXACT_PREFILL_FAMILIES)
-            if serial:
-                for j in todo:
-                    self._admit([j])
-            else:
-                self._admit(todo)
+            with span("elis.engine.admit", jobs=len(todo)):
+                if serial:
+                    for j in todo:
+                        self._admit([j])
+                else:
+                    self._admit(todo)
         return [self.slot_of[j.job_id] for j in jobs]
 
     def _admit(self, jobs: Sequence[Job]) -> List[int]:
@@ -676,24 +696,27 @@ class InferenceEngine:
         for i, t in enumerate(token_lists):
             toks[i, : len(t)] = t
             last_index[i] = len(t) - 1
-        cacheN = T.init_cache(self.model_cfg, bb, self.cfg.max_len)
+        with span("elis.engine.alloc"):
+            cacheN = T.init_cache(self.model_cfg, bb, self.cfg.max_len)
         if self._prefill_impl != self.cfg.attn_impl:
             self._warn_once(
                 "pallas_prefill",
                 "attn_impl='pallas' prefill under a mesh runs the XLA "
                 "attention path: the prefill kernels are single-device "
                 "(decode keeps the shard_map'd kernel)")
-        self.num_prefill_dispatches += 1
-        logits, cacheN = self._prefill(self.params, jnp.asarray(toks), cacheN,
-                                       jnp.asarray(last_index))
-        # per-row true lengths (prefill stamps the padded length)
-        cacheN["len"] = jnp.asarray(
-            true_lens + [0] * (bb - len(jobs)), jnp.int32)
+        with span("elis.engine.prefill"):
+            self.num_prefill_dispatches += 1
+            logits, cacheN = self._prefill(self.params, jnp.asarray(toks),
+                                           cacheN, jnp.asarray(last_index))
         slots = [s for s, owner in enumerate(self.slot_job)
                  if owner is None][: len(jobs)]
-        self.cache = self._canon_cache(
-            _scatter_slots(self.cache, cacheN, slots, len(jobs)))
-        logits_np = np.asarray(logits)
+        with span("elis.engine.scatter"):
+            # per-row true lengths (prefill stamps the padded length)
+            cacheN["len"] = jnp.asarray(
+                true_lens + [0] * (bb - len(jobs)), jnp.int32)
+            self.cache = self._canon_cache(
+                _scatter_slots(self.cache, cacheN, slots, len(jobs)))
+        logits_np = self._sync(logits)
         for i, (job, slot) in enumerate(zip(jobs, slots)):
             self.slot_job[slot] = job.job_id
             self.slot_of[job.job_id] = slot
@@ -739,9 +762,12 @@ class InferenceEngine:
             return [], []
         # swap-in: batch members with a host-stashed cache restore it
         # instead of paying recompute (KV offload tier)
-        for job in jobs:
-            if not self.has_job(job.job_id) and self.has_stash(job.job_id):
-                self.restore_job(job)
+        stashed = [j for j in jobs
+                   if not self.has_job(j.job_id) and self.has_stash(j.job_id)]
+        if stashed:
+            with span("elis.engine.restore", jobs=len(stashed)):
+                for job in stashed:
+                    self.restore_job(job)
         chunked = prefill_chunk is not None
         if chunked and not self.chunk_supported():
             self._warn_once(
@@ -752,9 +778,10 @@ class InferenceEngine:
                 "to one-shot prefill")
             chunked = False
         if chunked:
-            for job in jobs:
-                if not self.has_job(job.job_id):
-                    self._alloc_slot(job)
+            fresh = [j for j in jobs if not self.has_job(j.job_id)]
+            if fresh:
+                with span("elis.engine.admit", jobs=len(fresh)):
+                    self._alloc_slots(fresh)
             # decode eligibility is decided BEFORE the chunk runs: the job
             # completing its final chunk this window decodes next window
             incomplete = [j for j in jobs
@@ -762,13 +789,15 @@ class InferenceEngine:
             decode_jobs = [j for j in jobs
                            if not self.prefill_incomplete(j.job_id)]
             if incomplete:
-                self._run_chunk(incomplete[0], prefill_chunk)
+                with span("elis.engine.chunk"):
+                    self._run_chunk(incomplete[0], prefill_chunk)
         else:
             self.add_jobs(jobs)
             decode_jobs = list(jobs)
         results = {j.job_id: ([], False) for j in jobs}
         if decode_jobs:
-            self._decode_jobs(decode_jobs, window, results)
+            with span("elis.engine.decode", batch=len(decode_jobs)):
+                self._decode_jobs(decode_jobs, window, results)
         out_tokens = [list(results[j.job_id][0]) for j in jobs]
         finished = [results[j.job_id][1] for j in jobs]
         # publish each job's materialized context (prompt + generated KV,
@@ -787,83 +816,89 @@ class InferenceEngine:
         """One masked/compacted decode dispatch for ``jobs`` (all holding
         fully-prefilled slots); writes (tokens, finished) into ``results``."""
         slots = [self.slot_of[job.job_id] for job in jobs]
-        prev_lens = np.asarray(self.cache["len"]).copy()
+        prev_lens = self._sync(self.cache["len"]).copy()
         ms = self.cfg.max_slots
         order = sorted(slots)
         db = min(batch_bucket(len(order)), ms)
         compact = self.cfg.masked_decode and db < ms
-        if compact:
-            # decode only the scheduled slots, padded to the batch bucket
-            # (pad rows duplicate a real slot but start dead, so they are
-            # frozen no-ops); gather/scatter costs one pass over the active
-            # slots' cache per *window*, decode reads it K times
-            gidx = np.asarray(order + [order[0]] * (db - len(order)),
-                              np.int32)
-            sub_cache = self._canon_cache(
-                _gather_slots(self.cache, jnp.asarray(gidx)))
-            sub_last = jnp.asarray(self.last_token[gidx])
-            alive0 = np.zeros((db,), bool)
-            alive0[: len(order)] = True
-            row_of = {slot: r for r, slot in enumerate(order)}
-        else:
-            sub_cache = self.cache
-            sub_last = jnp.asarray(self.last_token)
-            if self.cfg.masked_decode:
-                # full-width dispatch, but unscheduled slots stay frozen
-                alive0 = np.zeros((ms,), bool)
-                alive0[slots] = True
+        with span("elis.engine.gather"):
+            if compact:
+                # decode only the scheduled slots, padded to the batch bucket
+                # (pad rows duplicate a real slot but start dead, so they are
+                # frozen no-ops); gather/scatter costs one pass over the active
+                # slots' cache per *window*, decode reads it K times
+                gidx = np.asarray(order + [order[0]] * (db - len(order)),
+                                  np.int32)
+                sub_cache = self._canon_cache(
+                    _gather_slots(self.cache, jnp.asarray(gidx)))
+                sub_last = jnp.asarray(self.last_token[gidx])
+                alive0 = np.zeros((db,), bool)
+                alive0[: len(order)] = True
+                row_of = {slot: r for r, slot in enumerate(order)}
             else:
-                # pre-fast-path baseline: every slot advances every window
-                alive0 = np.ones((ms,), bool)
-            row_of = {s: s for s in slots}
-        fn = self._decode_window(window, int(sub_last.shape[0]))
-        self._key, sub_key = jax.random.split(self._key)
-        self.num_decode_dispatches += 1
-        new_cache, toks = fn(self.params, sub_cache, sub_last,
-                             jnp.asarray(alive0), sub_key)
-        toks = np.asarray(toks)  # (rows, K)
-        if compact:
+                sub_cache = self.cache
+                sub_last = jnp.asarray(self.last_token)
+                if self.cfg.masked_decode:
+                    # full-width dispatch, but unscheduled slots stay frozen
+                    alive0 = np.zeros((ms,), bool)
+                    alive0[slots] = True
+                else:
+                    # pre-fast-path baseline: every slot advances every window
+                    alive0 = np.ones((ms,), bool)
+                row_of = {s: s for s in slots}
+        with span("elis.engine.decode_run"):
+            fn = self._decode_window(window, int(sub_last.shape[0]))
+            self._key, sub_key = jax.random.split(self._key)
+            self.num_decode_dispatches += 1
+            new_cache, toks = fn(self.params, sub_cache, sub_last,
+                                 jnp.asarray(alive0), sub_key)
+        toks = self._sync(toks)  # (rows, K)
+        with span("elis.engine.scatter"):
+            if compact:
+                self.cache = self._canon_cache(
+                    _scatter_slots(self.cache, new_cache, order, len(order)))
+            else:
+                self.cache = new_cache
+        lens = self._sync(self.cache["len"]).copy()
+        with span("elis.engine.emit"):
+            for job in jobs:
+                slot = self.slot_of[job.job_id]
+                scanned = toks[row_of[slot]].tolist()
+                pending = self._pending_first.pop(job.job_id, None)
+                if pending is not None:
+                    # first emission comes from the prefill logits; the scan's
+                    # K-th token is unconsumed (its cache write is rolled back)
+                    seq = [pending] + scanned[: window - 1]
+                    consumed_scanned = len(seq) - 1
+                else:
+                    seq = scanned[:window]
+                    consumed_scanned = len(seq)
+                cap = self.cfg.max_output
+                if self.cfg.respect_job_max and job.true_output_len > 0:
+                    cap = min(cap, job.true_output_len)
+                if self.cfg.eos_id in seq:
+                    cut = seq.index(self.cfg.eos_id) + 1
+                    dropped = len(seq) - cut
+                    seq = seq[:cut]
+                    consumed_scanned -= dropped
+                    fin = True
+                else:
+                    fin = False
+                room = cap - job.tokens_generated
+                if len(seq) >= room:
+                    dropped = len(seq) - room
+                    seq = seq[:room]
+                    consumed_scanned -= dropped
+                    fin = True
+                results[job.job_id] = (seq, fin)
+                self.last_token[slot, 0] = seq[-1] if seq else PAD_ID
+                # the cache pointer advances exactly one position per consumed
+                # scan write — robust to both EOS freezing (which already
+                # stopped advancing) and cap truncation (which did not)
+                lens[slot] = prev_lens[slot] + max(consumed_scanned, 0)
+        with span("elis.engine.len_write"):
             self.cache = self._canon_cache(
-                _scatter_slots(self.cache, new_cache, order, len(order)))
-        else:
-            self.cache = new_cache
-        lens = np.asarray(self.cache["len"]).copy()
-        for job in jobs:
-            slot = self.slot_of[job.job_id]
-            scanned = toks[row_of[slot]].tolist()
-            pending = self._pending_first.pop(job.job_id, None)
-            if pending is not None:
-                # first emission comes from the prefill logits; the scan's
-                # K-th token is unconsumed (its cache write is rolled back)
-                seq = [pending] + scanned[: window - 1]
-                consumed_scanned = len(seq) - 1
-            else:
-                seq = scanned[:window]
-                consumed_scanned = len(seq)
-            cap = self.cfg.max_output
-            if self.cfg.respect_job_max and job.true_output_len > 0:
-                cap = min(cap, job.true_output_len)
-            if self.cfg.eos_id in seq:
-                cut = seq.index(self.cfg.eos_id) + 1
-                dropped = len(seq) - cut
-                seq = seq[:cut]
-                consumed_scanned -= dropped
-                fin = True
-            else:
-                fin = False
-            room = cap - job.tokens_generated
-            if len(seq) >= room:
-                dropped = len(seq) - room
-                seq = seq[:room]
-                consumed_scanned -= dropped
-                fin = True
-            results[job.job_id] = (seq, fin)
-            self.last_token[slot, 0] = seq[-1] if seq else PAD_ID
-            # the cache pointer advances exactly one position per consumed
-            # scan write — robust to both EOS freezing (which already
-            # stopped advancing) and cap truncation (which did not)
-            lens[slot] = prev_lens[slot] + max(consumed_scanned, 0)
-        self.cache = self._canon_cache({**self.cache, "len": jnp.asarray(lens)})
+                {**self.cache, "len": jnp.asarray(lens)})
 
 
 # --------------------------------------------------------------------------- #
@@ -917,29 +952,30 @@ class EngineExecutor(Backend):
     def execute(self, node: int, jobs: Sequence[Job], window: int,
                 now: float, prefill_chunk: Optional[int] = None
                 ) -> ExecResult:
-        eng = self.engines[node]
-        t0 = time.perf_counter()
-        # capacity: evict nothing here — the frontend already chose the batch;
-        # engine must have slots for every scheduled job
-        needed = sum(1 for job in jobs if not eng.has_job(job.job_id))
-        if needed > eng.free_slots():
-            raise RuntimeError(
-                f"node {node}: batch needs {needed} free slots, "
-                f"engine has {eng.free_slots()}"
-            )
-        for j in jobs:
-            if eng.has_stash(j.job_id):
-                self.n_swapins += 1
-                self.swapin_tokens += j.prefilled_tokens
-        tokens, finished = eng.run_window(jobs, window,
-                                          prefill_chunk=prefill_chunk)
-        dur = time.perf_counter() - t0
-        dur += self._pending_swap_s.pop(node, 0.0)
-        self.window_log.append({
-            "node": node, "batch": len(jobs), "window": window,
-            "duration_s": dur, "tokens": sum(len(t) for t in tokens),
-        })
-        return ExecResult(dur, tokens, finished)
+        with span("elis.engine.execute", batch=len(jobs)):
+            eng = self.engines[node]
+            t0 = time.perf_counter()
+            # capacity: evict nothing here — the frontend already chose the
+            # batch; engine must have slots for every scheduled job
+            needed = sum(1 for job in jobs if not eng.has_job(job.job_id))
+            if needed > eng.free_slots():
+                raise RuntimeError(
+                    f"node {node}: batch needs {needed} free slots, "
+                    f"engine has {eng.free_slots()}"
+                )
+            for j in jobs:
+                if eng.has_stash(j.job_id):
+                    self.n_swapins += 1
+                    self.swapin_tokens += j.prefilled_tokens
+            tokens, finished = eng.run_window(jobs, window,
+                                              prefill_chunk=prefill_chunk)
+            dur = time.perf_counter() - t0
+            dur += self._pending_swap_s.pop(node, 0.0)
+            self.window_log.append({
+                "node": node, "batch": len(jobs), "window": window,
+                "duration_s": dur, "tokens": sum(len(t) for t in tokens),
+            })
+            return ExecResult(dur, tokens, finished)
 
     def evict(self, node: int, job: Job) -> None:
         eng = self.engines[node]
@@ -1025,6 +1061,7 @@ class EngineExecutor(Backend):
                 "chunk_traces": eng.num_chunk_traces,
                 "chunk_dispatches": eng.num_chunk_dispatches,
                 "resume_context_tokens": eng.resume_context_tokens,
+                "host_syncs": eng.num_host_syncs,
                 "windows_executed": windows.get(n, 0)}
             for n, eng in self.engines.items()
         }
@@ -1036,7 +1073,7 @@ class EngineExecutor(Backend):
         agg = {"prefill_traces": 0, "prefill_dispatches": 0,
                "decode_traces": 0, "decode_dispatches": 0,
                "chunk_traces": 0, "chunk_dispatches": 0,
-               "resume_context_tokens": 0,
+               "resume_context_tokens": 0, "host_syncs": 0,
                "windows_executed": len(self.window_log),
                "swapouts": self.n_swapouts, "swapins": self.n_swapins,
                "swapout_tokens": self.swapout_tokens,
@@ -1049,7 +1086,7 @@ class EngineExecutor(Backend):
             for k in ("prefill_traces", "prefill_dispatches",
                       "decode_traces", "decode_dispatches",
                       "chunk_traces", "chunk_dispatches",
-                      "resume_context_tokens"):
+                      "resume_context_tokens", "host_syncs"):
                 agg[k] += per[k]
         return agg
 

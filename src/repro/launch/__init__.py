@@ -1,8 +1,5 @@
 """Distribution layer: production mesh, partition rules, dry-run, drivers."""
 from repro.launch.mesh import (
-    HBM_BW,
-    ICI_BW,
-    PEAK_FLOPS_BF16,
     batch_axes,
     make_mesh,
     make_production_mesh,
@@ -11,11 +8,8 @@ from repro.launch.mesh import (
 from repro.launch.shapes import LONG_CONTEXT_WINDOW, SHAPES, InputShape, input_specs, supported
 
 __all__ = [
-    "HBM_BW",
-    "ICI_BW",
     "InputShape",
     "LONG_CONTEXT_WINDOW",
-    "PEAK_FLOPS_BF16",
     "SHAPES",
     "batch_axes",
     "input_specs",

@@ -3,8 +3,6 @@
 A FUNCTION (not a module-level constant) so importing this module never
 touches JAX device state — the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
-
-TPU v5e hardware constants used by the roofline analysis live here too.
 """
 from __future__ import annotations
 
@@ -12,11 +10,6 @@ import numpy as np
 
 import jax
 from jax.sharding import AxisType
-
-# TPU v5e per-chip constants (roofline denominators)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
-ICI_BW = 50e9                 # bytes/s per link
 
 
 def make_mesh(shape, axes=("data", "model"), *, devices=None):

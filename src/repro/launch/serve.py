@@ -367,6 +367,9 @@ def main(argv=None) -> int:
           f"migrations={server.frontend.migrations}  "
           f"({len(finished)}/{len(responses)} finished)", file=sys.stderr)
     ec = executor.counters()
+    print(f"[serve] windows={ec['windows_executed']} "
+          f"host_syncs={ec['host_syncs']} (device-to-host reads)",
+          file=sys.stderr)
     if ec["chunk_dispatches"] or ec["swapouts"]:
         print(f"[serve] chunk_dispatches={ec['chunk_dispatches']} "
               f"(traces {ec['chunk_traces']})  "

@@ -41,6 +41,7 @@ def test_serve_main_in_process(capsys, keep_cache_dir):
         assert r["status"] == "finished"
         assert len(r["tokens"]) == r["n_tokens"] > 0
     assert "platform=cpu" in out.err and "attn=pallas" in out.err
+    assert "host_syncs=" in out.err
 
 
 def test_serve_main_fails_when_a_request_does_not_finish(
