@@ -40,7 +40,10 @@ Fast path (DESIGN.md §3.2–§3.4):
   * **compile/dispatch counters** — ``num_prefill_traces`` /
     ``num_prefill_dispatches`` / ``num_decode_traces`` /
     ``num_decode_dispatches`` mirror ``BGEPredictor``'s recompile-storm
-    hooks, and ``num_host_syncs`` counts device-to-host reads (each one an
+    hooks, ``num_decode_kv_inplace`` counts the decode dispatches whose
+    program read the stacked KV cache in place (the Pallas kernel over a
+    dense cache, ``T.decode_reads_in_place``), and ``num_host_syncs``
+    counts device-to-host reads (each one an
     ``elis.engine.sync`` span, :mod:`repro.spans`); the jitted programs are
     named ``prefill``, ``prefill_chunk`` and ``decode_window``, so a device
     trace names them ``jit_prefill``, ``jit_prefill_chunk`` and
@@ -144,6 +147,35 @@ def _scatter_slots(big, small, slots: Sequence[int], n: int):
     return jax.tree_util.tree_map_with_path(put, big, small)
 
 
+def make_decode_window(model_cfg, cfg: EngineConfig, window: int,
+                       mesh=None):
+    """The decode program of one window: ``window`` steps of
+    ``T.decode_step`` over a slot (sub-)cache, sampling each next token.
+    ``decode_window(params, cache, last_tokens (B, 1), alive (B,), rng)
+    -> (cache, tokens (B, window))``; the engine jits it per (window,
+    batch), and it lowers from shapes alone."""
+
+    def decode_window(params, cache, last_tokens, alive, rng):
+        def step(carry, _):
+            cache, toks, alive, rng = carry
+            logits, cache = T.decode_step(params, model_cfg, toks, cache,
+                                          attn_impl=cfg.attn_impl,
+                                          active=alive, mesh=mesh)
+            rng, sub = jax.random.split(rng)
+            nxt = sample(logits[:, -1, :], sub, cfg.sampler,
+                         active=alive, pad_token=PAD_ID)[:, None]
+            # EOS freezes the slot for the rest of the scan: no
+            # KV/state write, no len advance, PAD emissions
+            alive = alive & (nxt[:, 0] != cfg.eos_id)
+            return (cache, nxt, alive, rng), nxt[:, 0]
+
+        (cache, _, _, _), toks = jax.lax.scan(
+            step, (cache, last_tokens, alive, rng), None, length=window)
+        return cache, jnp.swapaxes(toks, 0, 1)
+
+    return decode_window
+
+
 class InferenceEngine:
     """One backend worker's execution engine (one model, N slots).
 
@@ -228,6 +260,9 @@ class InferenceEngine:
         #: buckets, not calls
         self.num_prefill_dispatches = 0
         self.num_decode_dispatches = 0
+        #: decode dispatches whose program read the KV cache in place
+        #: (``T.decode_reads_in_place``): no layer copied out and back
+        self.num_decode_kv_inplace = 0
         #: device-to-host reads (``_sync``)
         self.num_host_syncs = 0
         self._prefill_traces = 0
@@ -585,30 +620,12 @@ class InferenceEngine:
         """jit per (window length, compacted batch size) — both static."""
         key2 = (window, batch)
         if key2 not in self._window_cache:
-            mc, ec = self.model_cfg, self.cfg
+            program = make_decode_window(self.model_cfg, self.cfg, window,
+                                         self.mesh)
 
             def decode_window(params, cache, last_tokens, alive, rng):
                 self._decode_traces += 1  # side effect: once per shape
-
-                def step(carry, _):
-                    cache, toks, alive, rng = carry
-                    logits, cache = T.decode_step(params, mc, toks, cache,
-                                                  attn_impl=ec.attn_impl,
-                                                  active=alive,
-                                                  mesh=self.mesh)
-                    rng, sub = jax.random.split(rng)
-                    nxt = sample(logits[:, -1, :], sub, ec.sampler,
-                                 active=alive, pad_token=PAD_ID)[:, None]
-                    # EOS freezes the slot for the rest of the scan: no
-                    # KV/state write, no len advance, PAD emissions
-                    alive = alive & (nxt[:, 0] != ec.eos_id)
-                    return (cache, nxt, alive, rng), nxt[:, 0]
-
-                (cache, _, _, _), toks = jax.lax.scan(
-                    step, (cache, last_tokens, alive, rng), None,
-                    length=window
-                )
-                return cache, jnp.swapaxes(toks, 0, 1)
+                return program(params, cache, last_tokens, alive, rng)
 
             self._window_cache[key2] = self._jit(
                 decode_window, (self._param_sh, self._cache_sh, self._repl,
@@ -850,6 +867,9 @@ class InferenceEngine:
             fn = self._decode_window(window, int(sub_last.shape[0]))
             self._key, sub_key = jax.random.split(self._key)
             self.num_decode_dispatches += 1
+            if T.decode_reads_in_place(self.model_cfg, sub_cache,
+                                       self.cfg.attn_impl):
+                self.num_decode_kv_inplace += 1
             new_cache, toks = fn(self.params, sub_cache, sub_last,
                                  jnp.asarray(alive0), sub_key)
         toks = self._sync(toks)  # (rows, K)
@@ -1058,6 +1078,7 @@ class EngineExecutor(Backend):
                 "prefill_dispatches": eng.num_prefill_dispatches,
                 "decode_traces": eng.num_decode_traces,
                 "decode_dispatches": eng.num_decode_dispatches,
+                "decode_kv_inplace": eng.num_decode_kv_inplace,
                 "chunk_traces": eng.num_chunk_traces,
                 "chunk_dispatches": eng.num_chunk_dispatches,
                 "resume_context_tokens": eng.resume_context_tokens,
@@ -1072,6 +1093,7 @@ class EngineExecutor(Backend):
         :meth:`node_counters` keeps the per-pod breakdown."""
         agg = {"prefill_traces": 0, "prefill_dispatches": 0,
                "decode_traces": 0, "decode_dispatches": 0,
+               "decode_kv_inplace": 0,
                "chunk_traces": 0, "chunk_dispatches": 0,
                "resume_context_tokens": 0, "host_syncs": 0,
                "windows_executed": len(self.window_log),
@@ -1085,6 +1107,7 @@ class EngineExecutor(Backend):
         for per in self.node_counters().values():
             for k in ("prefill_traces", "prefill_dispatches",
                       "decode_traces", "decode_dispatches",
+                      "decode_kv_inplace",
                       "chunk_traces", "chunk_dispatches",
                       "resume_context_tokens", "host_syncs"):
                 agg[k] += per[k]

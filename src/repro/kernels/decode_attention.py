@@ -17,6 +17,10 @@ per group rather than once per query head.
 Per-sequence dynamic state (valid cache length, absolute query position)
 arrives via scalar prefetch (SMEM) so slots at different generation depths
 batch together — exactly what ELIS's continuous batching produces.  The
+K/V operands may be the model's whole stacked cache ``(n_layers, B, L,
+KH, D)``: a third scalar-prefetch operand names the layer, and the K/V
+index map reads that layer's blocks straight out of the stack, so the
+decode layer loop never copies a layer out (``T.decode_step``).  The
 int8-KV variant runs the same body with per-token fp32 scales applied to
 the scores and probabilities, so HBM traffic is the int8 bytes.
 
@@ -46,6 +50,7 @@ _NT = (((1,), (1,)), ((), ()))
 
 def _decode_kernel(
     kv_len_ref, q_off_ref,  # scalar-prefetch (SMEM): (B,) each
+    layer_ref,              # scalar-prefetch (SMEM): (1,), read by index maps
     q_ref, k_ref, v_ref, *rest,
     scale: float,
     block_k: int,
@@ -104,23 +109,27 @@ def _decode_kernel(
 
 
 def _flash_decode(q, k, v, scales, *, kv_len, q_offset, window, block_k,
-                  interpret):
+                  interpret, layer=0):
     b, sq, h, d = q.shape
     assert sq == 1
-    L, kh = k.shape[1], k.shape[2]
+    if k.ndim == 4:  # one layer's buffers: a stack of one
+        k, v = k[None], v[None]
+    L, kh = k.shape[2], k.shape[3]
     rep = h // kh
     block_k = min(block_k, L)
     assert L % block_k == 0, (L, block_k)
     n_k = L // block_k
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
     q_offset = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,))
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / math.sqrt(d), block_k=block_k,
         n_kv_blocks=n_k, window=window, quantized=scales is not None)
     q_spec = pl.BlockSpec((None, 1, h, d), lambda b_, ki, *_: (b_, 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, block_k, kh, d),
-                           lambda b_, ki, *_: (b_, ki, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, kh, d),
+                           lambda b_, ki, lens, offs, lay: (lay[0], b_, ki,
+                                                            0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k, v]
     if scales is not None:
@@ -128,7 +137,7 @@ def _flash_decode(q, k, v, scales, *, kv_len, q_offset, window, block_k,
                                   lambda b_, ki, *_: (b_, 0, ki))] * 2
         operands += [s.astype(jnp.float32).reshape(b, 1, L) for s in scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_k),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -143,7 +152,7 @@ def _flash_decode(q, k, v, scales, *, kv_len, q_offset, window, block_k,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
         interpret=interpret,
-    )(kv_len, q_offset, *operands)
+    )(kv_len, q_offset, layer, *operands)
 
 
 def flash_decode_int8(
@@ -169,7 +178,7 @@ def flash_decode_int8(
 
 def flash_decode(
     q: jnp.ndarray,  # (B, 1, H, D)
-    k: jnp.ndarray,  # (B, L, KH, D)
+    k: jnp.ndarray,  # (B, L, KH, D), or stacked (n_layers, B, L, KH, D)
     v: jnp.ndarray,
     *,
     kv_len: jnp.ndarray,  # (B,) or scalar
@@ -177,14 +186,20 @@ def flash_decode(
     window: Optional[int] = None,
     block_k: int = 128,
     interpret: bool = False,
+    layer=0,  # scalar: which layer of a stacked K/V to read
 ) -> jnp.ndarray:
+    """Decode attention of ``q`` over one layer's K/V.  Given the stacked
+    cache, ``layer`` (traced, scalar-prefetched) selects the layer and the
+    kernel reads its blocks in place; the result is bit-identical to the
+    call on ``k[layer], v[layer]``."""
     return _flash_decode(q, k, v, None, kv_len=kv_len, q_offset=q_offset,
-                         window=window, block_k=block_k, interpret=interpret)
+                         window=window, block_k=block_k, interpret=interpret,
+                         layer=layer)
 
 
 def flash_decode_sharded(
     q: jnp.ndarray,  # (B, 1, H, D), heads sharded on ``axis``
-    k: jnp.ndarray,  # (B, L, KH, D), kv heads sharded on ``axis``
+    k: jnp.ndarray,  # ([n_layers,] B, L, KH, D), kv heads sharded on ``axis``
     v: jnp.ndarray,
     *,
     kv_len: jnp.ndarray,    # (B,) or scalar, replicated
@@ -194,6 +209,7 @@ def flash_decode_sharded(
     window: Optional[int] = None,
     block_k: int = 128,
     interpret: bool = False,
+    layer=0,  # scalar, replicated: which layer of a stacked K/V to read
 ) -> jnp.ndarray:
     """:func:`flash_decode` under a tensor-parallel mesh.
 
@@ -209,11 +225,12 @@ def flash_decode_sharded(
     holds whole heads at the same GQA ratio (``H/tp ÷ KH/tp = H ÷ KH``);
     indivisible layouts (KV replicated by ``sanitize_specs``) must stay on
     the XLA path — the per-shard kernel would index the wrong KV head.
+    A stacked K/V keeps its layer axis whole on every shard.
     """
     from jax.sharding import PartitionSpec as P
 
     b, sq, h, d = q.shape
-    kh = k.shape[2]
+    kh = k.shape[-2]
     tp = int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis])
     if h % tp or kh % tp:
         raise ValueError(
@@ -222,19 +239,21 @@ def flash_decode_sharded(
             "and must use the XLA decode path")
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
     q_offset = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,))
+    layer = jnp.asarray(layer, jnp.int32)
 
-    def local(q_, k_, v_, kv_len_, q_offset_):
+    def local(q_, k_, v_, kv_len_, q_offset_, layer_):
         return flash_decode(q_, k_, v_, kv_len=kv_len_, q_offset=q_offset_,
                             window=window, block_k=block_k,
-                            interpret=interpret)
+                            interpret=interpret, layer=layer_)
 
     head_spec = P(None, None, axis, None)
+    kv_spec = P(*([None] * (k.ndim - 2)), axis, None)
     return jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(head_spec, head_spec, head_spec, P(None), P(None)),
+        in_specs=(head_spec, kv_spec, kv_spec, P(None), P(None), P()),
         out_specs=head_spec,
         # pallas_call carries no varying-axis rule; the output really is
         # head-sharded, so skipping the check is sound here
         check_vma=False,
-    )(q, k, v, kv_len, q_offset)
+    )(q, k, v, kv_len, q_offset, layer)

@@ -36,8 +36,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
                                              "shard_axis"))
 def flash_decode(q, k, v, *, kv_len, q_offset,
                  window: Optional[int] = None, block_k: int = 128,
-                 mesh=None, shard_axis: str = "model"):
-    """Flash-decode dispatch.  With ``mesh`` (a static arg, so single- and
+                 mesh=None, shard_axis: str = "model", layer=0):
+    """Flash-decode dispatch.  ``k``/``v`` are one layer's ``(B, L, KH,
+    D)`` buffers or the stacked ``(n_layers, B, L, KH, D)`` cache, read
+    in place at ``layer``.  With ``mesh`` (a static arg, so single- and
     multi-device callers never share a stale trace) the kernel runs
     ``shard_map``-ped over ``shard_axis`` with Q/KV heads partitioned —
     bit-identical per head to the single-device kernel."""
@@ -45,11 +47,11 @@ def flash_decode(q, k, v, *, kv_len, q_offset,
         return _dec.flash_decode_sharded(
             q, k, v, kv_len=kv_len, q_offset=q_offset, mesh=mesh,
             axis=shard_axis, window=window, block_k=block_k,
-            interpret=_interpret(),
+            interpret=_interpret(), layer=layer,
         )
     return _dec.flash_decode(
         q, k, v, kv_len=kv_len, q_offset=q_offset, window=window,
-        block_k=block_k, interpret=_interpret(),
+        block_k=block_k, interpret=_interpret(), layer=layer,
     )
 
 

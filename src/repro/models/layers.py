@@ -97,18 +97,29 @@ def masked_span_write(buf: jnp.ndarray, start: jnp.ndarray, val: jnp.ndarray,
 
 
 def masked_row_write(buf: jnp.ndarray, slot: jnp.ndarray, val: jnp.ndarray,
-                     active=None) -> jnp.ndarray:
+                     active=None, layer=None) -> jnp.ndarray:
     """Write ``val`` (B, ...) into ``buf`` (B, L, ...) at per-row position
     ``slot`` (B,).  Rows with ``active=False`` keep their previous value —
     the no-op that lets frozen decode slots (EOS-finished or simply
     unoccupied) share a dispatch with live slots without corrupting their
     cache.  The select touches one position per row, so the masked write
-    costs a (B, ...) gather, not a whole-buffer copy."""
-    rows = jnp.arange(buf.shape[0])
+    costs a (B, ...) gather, not a whole-buffer copy.  With ``layer``,
+    ``buf`` is the stacked (n_layers, B, L, ...) cache and the rows land
+    at ``[layer, b, slot[b]]``: a scatter XLA does in place when ``buf``
+    is a loop carry."""
+    rows = jnp.arange(val.shape[0])
+    idx = (rows, slot) if layer is None else (layer, rows, slot)
     if active is not None:
         keep = active.reshape((-1,) + (1,) * (val.ndim - 1))
-        val = jnp.where(keep, val, buf[rows, slot])
-    return buf.at[rows, slot].set(val)
+        val = jnp.where(keep, val, buf[idx])
+    return buf.at[idx].set(val)
+
+
+def kernel_reads_cache(cache: KVCache, attn_impl: str) -> bool:
+    """Whether a decode step's attention read is the flash-decode kernel,
+    which reads a stacked cache in place at a layer index; the ring (SWA)
+    and int8 reads, and ``attn_impl="xla"``, take the layer's slice."""
+    return attn_impl == "pallas" and not cache.ring and not cache.quantized
 
 
 # --------------------------------------------------------------------------- #
@@ -380,6 +391,7 @@ def attention_block(
     active=None,
     valid_len=None,
     mesh=None,
+    layer=None,
 ) -> Tuple[jnp.ndarray, object]:
     """Full attention block: proj -> rope -> (cache update) -> sdpa -> out proj.
 
@@ -397,6 +409,11 @@ def attention_block(
     exactly as ``engine_shardings`` places them, per-slot lengths
     replicated, no collective inside the kernel (docs/kernels.md).
     Callers must only pass a mesh when both head axes divide it.
+    ``layer`` (traced scalar), decode only: ``cache`` is the whole stacked
+    (n_layers, B, L, KH, D) cache, carried through the layer loop; this
+    layer's row is written in place at ``[layer, b, slot]``, the kernel
+    reads the stack at ``layer`` (:func:`kernel_reads_cache`), the other
+    reads take ``buf[layer]``, and the stacked cache is returned.
     Chunked prefill: ``cache`` given AND x is (B, C>1, d) — the C fresh
     tokens start at absolute position ``cur_index`` (B,) and only the first
     ``valid_len`` (B,) of them are real (the rest is bucket padding).  The
@@ -440,7 +457,7 @@ def attention_block(
         new_kv = (k, v)
     else:
         kbuf, vbuf = cache.k, cache.v
-        L = kbuf.shape[1]
+        L = kbuf.shape[-3]
         ringed = cache.ring
         # cur_index may be scalar or per-batch (B,) under continuous batching
         cur = jnp.broadcast_to(jnp.asarray(cur_index), (b,))
@@ -462,32 +479,39 @@ def attention_block(
         if cache.quantized:
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
-            kbuf = masked_row_write(kbuf, slot, kq[:, 0], active)
-            vbuf = masked_row_write(vbuf, slot, vq[:, 0], active)
-            k_sc = masked_row_write(cache.k_scale, slot, ks[:, 0], active)
-            v_sc = masked_row_write(cache.v_scale, slot, vs[:, 0], active)
-            kread = dequantize_kv(kbuf, k_sc, q.dtype)
-            vread = dequantize_kv(vbuf, v_sc, q.dtype)
+            kbuf = masked_row_write(kbuf, slot, kq[:, 0], active, layer)
+            vbuf = masked_row_write(vbuf, slot, vq[:, 0], active, layer)
+            k_sc = masked_row_write(cache.k_scale, slot, ks[:, 0], active,
+                                    layer)
+            v_sc = masked_row_write(cache.v_scale, slot, vs[:, 0], active,
+                                    layer)
         else:
-            kbuf = masked_row_write(kbuf, slot, k[:, 0], active)
-            vbuf = masked_row_write(vbuf, slot, v[:, 0], active)
+            kbuf = masked_row_write(kbuf, slot, k[:, 0], active, layer)
+            vbuf = masked_row_write(vbuf, slot, v[:, 0], active, layer)
             k_sc = v_sc = None
-            kread, vread = kbuf, vbuf
-        if ringed:
-            # absolute position of each buffer slot given cur tokens seen:
-            # slot i holds the largest position p <= cur with p % L == i
-            idx = jnp.arange(L)[None, :]
-            k_pos = idx + ((cur[:, None] - idx) // L) * L
-            k_pos = jnp.where(k_pos < 0, -1_000_000_000, k_pos)
-            out = sdpa(q, kread, vread, causal=True, q_offset=cur,
-                       window=window, ring_offset=k_pos)
-        else:
-            if attn_impl == "pallas" and not cache.quantized:
-                from repro.kernels import ops as kops
+        if kernel_reads_cache(cache, attn_impl):
+            from repro.kernels import ops as kops
 
-                out = kops.flash_decode(q, kread, vread, kv_len=cur + 1,
-                                        q_offset=cur, window=window,
-                                        mesh=mesh)
+            out = kops.flash_decode(q, kbuf, vbuf, kv_len=cur + 1,
+                                    q_offset=cur, window=window, mesh=mesh,
+                                    layer=0 if layer is None else layer)
+        else:
+            def at_layer(buf):
+                return buf if layer is None else buf[layer]
+
+            kread, vread = at_layer(kbuf), at_layer(vbuf)
+            if cache.quantized:
+                kread = dequantize_kv(kread, at_layer(k_sc), q.dtype)
+                vread = dequantize_kv(vread, at_layer(v_sc), q.dtype)
+            if ringed:
+                # absolute position of each buffer slot given cur tokens
+                # seen: slot i holds the largest position p <= cur with
+                # p % L == i
+                idx = jnp.arange(L)[None, :]
+                k_pos = idx + ((cur[:, None] - idx) // L) * L
+                k_pos = jnp.where(k_pos < 0, -1_000_000_000, k_pos)
+                out = sdpa(q, kread, vread, causal=True, q_offset=cur,
+                           window=window, ring_offset=k_pos)
             else:
                 out = sdpa(q, kread, vread, causal=True, q_offset=cur,
                            kv_len=cur + 1, window=window)

@@ -187,11 +187,12 @@ def unembed(params: Params, cfg, h: jnp.ndarray) -> jnp.ndarray:
 
 def _dense_body(cfg, attn_impl, moe_impl, lp: Params, x, cos_sin,
                 cache=None, cur_index=None, active=None, valid_len=None,
-                mesh=None):
+                mesh=None, layer=None):
     h = L.apply_norm(cfg, lp["attn_norm"], x)
     attn_out, kv = L.attention_block(
         lp["attn"], cfg, h, cos_sin, cache=cache, cur_index=cur_index,
         attn_impl=attn_impl, active=active, valid_len=valid_len, mesh=mesh,
+        layer=layer,
     )
     x = x + attn_out
     h = L.apply_norm(cfg, lp["mlp_norm"], x)
@@ -610,6 +611,14 @@ def prefill_chunk(params: Params, cfg, batch: Dict, cache: Cache,
 # =========================================================================== #
 
 
+def decode_reads_in_place(cfg, cache: Cache, attn_impl: str) -> bool:
+    """Whether :func:`decode_step` reads this cache without copying a
+    layer out: the dense layer loop carries the stacked KV cache, and the
+    flash-decode kernel reads each layer from the stack by index."""
+    return (cfg.family in ("dense", "moe", "vlm")
+            and L.kernel_reads_cache(cache["kv"], attn_impl))
+
+
 def decode_step(params: Params, cfg, tokens: jnp.ndarray, cache: Cache,
                 *, attn_impl: str = "xla", moe_impl: str = "grouped",
                 active: Optional[jnp.ndarray] = None, mesh=None):
@@ -646,36 +655,21 @@ def decode_step(params: Params, cfg, tokens: jnp.ndarray, cache: Cache,
     new_cache = dict(cache)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        kvc = cache["kv"]
-        ring = kvc.ring
-        quant = kvc.quantized
-
+        # the stacked cache rides in the carry: each layer writes its row
+        # in place and reads its own layer by index.  As scan inputs and
+        # outputs, each layer's whole K/V would be sliced out of the stack
+        # and written back, every step
         def body(carry, inp):
-            x, aux = carry
-            if quant:
-                lp, kb, vb, ksc, vsc = inp
-                lc = KVCache(kb, vb, ring, ksc, vsc)
-            else:
-                lp, kb, vb = inp
-                lc = KVCache(kb, vb, ring)
-            x, nkv, a = _dense_body(cfg, attn_impl, moe_impl, lp, x, cos_sin,
-                                    cache=lc, cur_index=cur, active=active,
-                                    mesh=mesh)
-            if quant:
-                return (x, aux + a), (nkv.k, nkv.v, nkv.k_scale, nkv.v_scale)
-            return (x, aux + a), (nkv.k, nkv.v)
+            x, kv = carry
+            li, lp = inp
+            x, kv, _ = _dense_body(cfg, attn_impl, moe_impl, lp, x, cos_sin,
+                                   cache=kv, cur_index=cur, active=active,
+                                   mesh=mesh, layer=li)
+            return (x, kv), None
 
-        if quant:
-            (h, _), (knew, vnew, ksnew, vsnew) = layer_scan(
-                body, (h, jnp.float32(0)),
-                (params["layers"], kvc.k, kvc.v, kvc.k_scale, kvc.v_scale),
-            )
-            new_cache["kv"] = KVCache(knew, vnew, ring, ksnew, vsnew)
-        else:
-            (h, _), (knew, vnew) = layer_scan(
-                body, (h, jnp.float32(0)), (params["layers"], kvc.k, kvc.v)
-            )
-            new_cache["kv"] = KVCache(knew, vnew, ring)
+        kvc = cache["kv"]
+        (h, new_cache["kv"]), _ = layer_scan(
+            body, (h, kvc), (jnp.arange(kvc.k.shape[0]), params["layers"]))
     elif cfg.family == "ssm":
         def body(x, inp):
             lp, st = inp

@@ -99,3 +99,60 @@ def test_long_context_ring_decode_stays_finite():
         lg, cache = step(toks[:, i : i + 1], cache)
         assert not jnp.any(jnp.isnan(lg))
     assert int(cache["len"][0]) == 40
+
+
+def _decode_step_sliced(params, cfg, tokens, cache, attn_impl, active):
+    """Reference layer loop: each layer's K/V is a scan input, sliced out
+    of the stacked cache, and a scan output, stacked back."""
+    from repro.models import layers as L
+    from repro.models import transformer as T
+
+    cur = cache["len"]
+    cos_sin = L.positional_cos_sin(cfg, cur[:, None])
+
+    def body(x, inp):
+        lp, layer_kv = inp
+        x, kv, _ = T._dense_body(cfg, attn_impl, "grouped", lp, x, cos_sin,
+                                 cache=layer_kv, cur_index=cur,
+                                 active=active)
+        return x, kv
+
+    h, kv = jax.lax.scan(body, params["embed"][tokens],
+                         (params["layers"], cache["kv"]))
+    return T.unembed(params, cfg, h), {
+        "len": jnp.where(active, cur + 1, cur), "kv": kv}
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", ["dense", "ring", "int8"])
+def test_decode_carried_cache_matches_sliced_layers(attn_impl, kind):
+    """The layer loop carries the stacked cache and writes each row in
+    place at its layer index; every read (kernel, XLA, ring, int8) gives
+    bitwise the logits and cache of the loop that slices each layer out
+    and stacks it back, and a frozen row's bytes stay untouched."""
+    cfg = get_config("qwen2-1.5b").reduced()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    B = 3
+    cache = init_cache(cfg, B, 32, sliding_window=16 if kind == "ring"
+                       else None,
+                       kv_dtype="int8" if kind == "int8" else None)
+    assert cache["kv"].ring == (kind == "ring")
+    batch = _batch(cfg, jax.random.PRNGKey(1), B, 12)
+    _, cache = prefill(params, cfg, batch, cache)
+    active = jnp.array([True, False, True])
+    nxt = jax.random.randint(jax.random.PRNGKey(2), (B, 6), 0, cfg.vocab_size)
+    got = want = cache
+    carried = jax.jit(lambda t, c: decode_step(
+        params, cfg, t, c, attn_impl=attn_impl, active=active))
+    sliced = jax.jit(lambda t, c: _decode_step_sliced(
+        params, cfg, t, c, attn_impl, active))
+    for i in range(6):  # the ring wraps at position 16
+        lg, got = carried(nxt[:, i:i + 1], got)
+        lw, want = sliced(nxt[:, i:i + 1], want)
+        assert jnp.array_equal(lg, lw), i
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert jnp.array_equal(a, b)
+    for a, b in zip(jax.tree_util.tree_leaves(got["kv"]),
+                    jax.tree_util.tree_leaves(cache["kv"])):
+        assert jnp.array_equal(a[:, 1], b[:, 1])  # frozen row, every layer

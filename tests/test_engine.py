@@ -234,6 +234,57 @@ def test_pallas_decode_matches_xla(setup):
     assert outs["xla"] == outs["pallas"]
 
 
+def test_inplace_decode_window_matches_xla(setup):
+    """One decode window on the pallas path, whose layer loop carries the
+    stacked KV cache and writes each row in place, against the XLA path:
+    an unscheduled slot, an empty slot and an EOS mid-window give the same
+    tokens and lengths, and the frozen rows' cache bytes stay identical in
+    every layer.  Only the kernel path counts as read in place."""
+    from repro.models import transformer as T
+
+    cfg, params = setup
+    prompts = [[11, 22, 33, 44], [5, 6, 7], [9, 8, 7, 6, 5]]
+    K = 6
+    # the window's first token is greedy token 2 (token 1 comes from the
+    # prefill logits): job 2 emits EOS at window step 1, job 0 never
+    eos = greedy_reference(cfg, params, prompts[2], 3)[2]
+    out = {}
+    for impl in ("xla", "pallas"):
+        eng = InferenceEngine(cfg, params, EngineConfig(
+            max_slots=4, max_len=64, max_output=64, eos_id=eos,
+            attn_impl=impl))
+        jobs = [_mk(i, p) for i, p in enumerate(prompts)]
+        eng.add_jobs(jobs)
+        before = eng.cache
+        alive = np.zeros((4,), bool)
+        alive[[eng.slot_of[0], eng.slot_of[2]]] = True
+        cache, toks = eng._decode_window(K, 4)(
+            eng.params, before, jnp.asarray(eng.last_token),
+            jnp.asarray(alive), jax.random.PRNGKey(1))
+        grew = np.asarray(cache["len"]) - np.asarray(before["len"])
+        assert grew[eng.slot_of[0]] == K
+        assert grew[eng.slot_of[2]] == 2  # frozen after its EOS
+        frozen = [eng.slot_of[1], 3]
+        assert not grew[frozen].any()
+        for new, old in zip(jax.tree_util.tree_leaves(cache["kv"]),
+                            jax.tree_util.tree_leaves(before["kv"])):
+            assert np.array_equal(np.asarray(new)[:, frozen],
+                                  np.asarray(old)[:, frozen])
+        out[impl] = np.asarray(toks), np.asarray(cache["len"])
+
+        ex = EngineExecutor({0: eng})
+        ex.execute(0, jobs[1:], K, 0.0)
+        c = ex.counters()
+        assert c["decode_dispatches"] == 1
+        assert c["decode_kv_inplace"] == (1 if impl == "pallas" else 0)
+    assert np.array_equal(out["xla"][0], out["pallas"][0])
+    assert np.array_equal(out["xla"][1], out["pallas"][1])
+    # the int8 and ring caches are read through XLA, a slice per layer
+    for kv in ({"kv_dtype": "int8"}, {"sliding_window": 32}):
+        assert not T.decode_reads_in_place(
+            cfg, T.init_cache(cfg, 4, 64, **kv), "pallas")
+
+
 # =========================================================================== #
 # EngineExecutor: counters + live<->sim calibration
 # =========================================================================== #

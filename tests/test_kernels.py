@@ -47,23 +47,35 @@ def test_flash_attention_sliding_window(window):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+@pytest.mark.parametrize("n_layers", [None, 3])
 @pytest.mark.parametrize("L,kh,d", [(256, 2, 64), (512, 4, 32), (128, 1, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_decode_sweep(L, kh, d, dtype):
+def test_flash_decode_sweep(L, kh, d, dtype, n_layers):
+    """One layer's K/V, or (``n_layers``) the stacked cache read at each
+    layer index: bitwise the call on that layer's own buffers."""
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     B, H = 3, 4
+    stack = () if n_layers is None else (n_layers,)
     q = rand(ks[0], (B, 1, H, d), dtype)
-    k = rand(ks[1], (B, L, kh, d), dtype)
-    v = rand(ks[2], (B, L, kh, d), dtype)
+    k = rand(ks[1], stack + (B, L, kh, d), dtype)
+    v = rand(ks[2], stack + (B, L, kh, d), dtype)
     kv_len = jnp.array([1, L // 2, L], jnp.int32)  # heterogeneous depths
     q_off = kv_len - 1
-    out = ops.flash_decode(q, k, v, kv_len=kv_len, q_offset=q_off)
-    want = ref.reference_decode_attention(q, k, v, kv_len=kv_len,
-                                          q_offset=q_off)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(want, np.float32),
-        atol=TOL[dtype], rtol=TOL[dtype],
-    )
+    if n_layers is None:
+        k, v = k[None], v[None]
+    for layer in range(k.shape[0]):
+        out = ops.flash_decode(q, k[layer], v[layer], kv_len=kv_len,
+                               q_offset=q_off)
+        if n_layers is not None:
+            stacked = ops.flash_decode(q, k, v, kv_len=kv_len,
+                                       q_offset=q_off, layer=layer)
+            assert np.array_equal(np.asarray(stacked), np.asarray(out))
+        want = ref.reference_decode_attention(q, k[layer], v[layer],
+                                              kv_len=kv_len, q_offset=q_off)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(want, np.float32),
+            atol=TOL[dtype], rtol=TOL[dtype],
+        )
 
 
 def test_flash_decode_window():

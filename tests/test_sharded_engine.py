@@ -475,6 +475,12 @@ def test_shard_map_kernel_matches_single_device_over_len_vectors():
                                 mesh=mesh)
         assert np.array_equal(np.asarray(ref), np.asarray(got)), \
             f"case {case}: sharded kernel diverged from single-device"
+        # the stacked (n_layers, B, L, KH, D) cache, read at a layer index
+        ks, vs = jnp.stack([v, k]), jnp.stack([k, v])
+        got = kops.flash_decode(q, ks, vs, kv_len=kv_len, q_offset=q_off,
+                                mesh=mesh, layer=1)
+        assert np.array_equal(np.asarray(ref), np.asarray(got)), \
+            f"case {case}: sharded stacked read diverged from single-device"
     # indivisible heads must be rejected at the kernel boundary too
     k3 = jnp.zeros((b, L, 3, d), jnp.float32)
     with pytest.raises(ValueError, match="divide"):

@@ -4,13 +4,16 @@ Interpret mode (every other kernel test) never applies Mosaic's block and
 memory rules; here each kernel is compiled by the TPU compiler for a
 *described* v5e, with no chip attached, and must come out as a Mosaic
 custom call.  Shapes: qwen2-1.5b attention (12 heads, 2 KV heads, head dim
-128) and mamba2-130m's SSD scan (24 heads, head dim 64, state 128).
+128) and mamba2-130m's SSD scan (24 heads, head dim 64, state 128).  The
+engine's whole decode window is compiled at qwen2-1.5b's serving shape, to
+show that it reads the stacked KV cache in place.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every xdist worker imports this
 file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,3 +119,47 @@ def test_sharded_decode_compiles_for_two_v5e(topo, no_persistent_cache):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" not in text and "all-gather" not in text
+
+
+def test_decode_window_reads_the_stacked_cache_in_place(
+        topo, no_persistent_cache, monkeypatch):
+    """The engine's decode window at qwen2-1.5b's serving shape (28
+    layers, 32 slots x 2048, pallas, K=2): the layer loop carries the
+    stacked KV cache, so no instruction makes a layer's K/V buffer and no
+    dynamic-update-slice writes a layer back; the kernel reads the stack."""
+    from repro.configs import get_config
+    from repro.engine.engine import EngineConfig, make_decode_window
+    from repro.kernels import ops as kops
+    from repro.models import transformer as T
+
+    monkeypatch.setattr(kops, "_interpret", lambda: False)  # Mosaic
+    cfg = get_config("qwen2-1.5b")
+    slots, max_len = 32, 2048
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    program = make_decode_window(cfg, EngineConfig(
+        max_slots=slots, max_len=max_len, attn_impl="pallas"), 2)
+    compiled = jax.jit(program).lower(
+        on_chip(T.abstract_params(cfg)),
+        on_chip(T.abstract_cache(cfg, slots, max_len)),
+        on_chip(jax.ShapeDtypeStruct((slots, 1), I32)),
+        on_chip(jax.ShapeDtypeStruct((slots,), jnp.bool_)),
+        on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))).compile()
+    lines = compiled.as_text().splitlines()
+    layer_kv = re.compile(r"\s*(ROOT )?%\S+ = bf16\[32,2048,2,128\]\{[^}]*\} "
+                          r"(?!parameter|tuple|get-tuple-element)[\w-]+\(")
+    made = [ln.strip()[:120] for ln in lines if layer_kv.match(ln)]
+    assert not made, made
+    stacked = "bf16[28,32,2048,2,128]"
+    written_back = [ln.strip()[:120] for ln in lines
+                    if f"= {stacked}" in ln and "dynamic-update-slice(" in ln]
+    assert not written_back, written_back
+    assert any(re.match(r"\s*%flash_decode\.\d+ = .*tpu_custom_call", ln)
+               for ln in lines)
+    # carrying the cache costs no temporaries: 132,798,464 B at this shape
+    assert compiled.memory_analysis().temp_size_in_bytes <= 132_798_464
